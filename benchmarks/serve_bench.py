@@ -84,7 +84,8 @@ import time
 
 # --devices must be applied before the first jax backend init (the
 # device count locks there; importing jax below is still safe)
-from repro.launch.compat import force_host_devices_from_argv, mesh_from_args
+from repro.launch.compat import (
+    enable_compile_cache, force_host_devices_from_argv, mesh_from_args)
 
 force_host_devices_from_argv(sys.argv)
 
@@ -741,10 +742,12 @@ def main():
                          "(0 = crash-recovery only)")
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = mesh_from_args(args.devices, args.mesh_shape)
 
-    base = registry.get_config(args.arch) if args.full else registry.get_smoke_config(args.arch)
+    base = (registry.serving_config(args.arch) if args.full
+            else registry.get_smoke_config(args.arch))
     m = args.num_instances
     max_context = args.max_context
     if base.family == "hybrid":
@@ -754,12 +757,10 @@ def main():
     cfg1 = base.with_(num_instances=1)
     cfg = base.with_(num_instances=m)
 
-    keys = jax.random.split(jax.random.PRNGKey(args.seed), m)
-    instances = [api.init(cfg1, k) for k in keys]
     t0 = time.perf_counter()
-    merged = C.merge_instances(instances, api.axes(cfg1))
-    jax.block_until_ready(jax.tree.leaves(merged)[0])
-    merge_ms = (time.perf_counter() - t0) * 1e3
+    merged = api.init_instances(cfg, jax.random.PRNGKey(args.seed))
+    jax.block_until_ready(merged)
+    grid_init_ms = (time.perf_counter() - t0) * 1e3
 
     rng = np.random.default_rng(args.seed)
     reqs = _mk_requests(rng, m, args.requests, cfg.vocab_size, args.max_new,
@@ -882,7 +883,7 @@ def main():
         "max_context": max_context,
         "mesh": dict(mesh.shape) if mesh is not None else None,
         "devices": num_devices,
-        "merge_ms": merge_ms,
+        "grid_init_ms": grid_init_ms,
         # compile-count trajectory: the chunked runtime's invariant is
         # two shapes (chunk + tail) per family regardless of workload
         "chunk_size": fused_server.prefill.chunk,

@@ -1,11 +1,2 @@
-"""NetFuse reproduction package.
-
-Importing ``repro`` installs the mesh-API compatibility shim
-(``launch/compat.py``): JAX releases disagree on how a mesh is made
-current (``jax.set_mesh`` / ``jax.sharding.use_mesh`` / the 0.4.x
-``with mesh:`` resource env), and the launch + serving layers — as well
-as the test-suite — use the modern ``jax.set_mesh`` spelling.
-"""
-from repro.launch import compat as _compat
-
-_compat.install()
+"""NetFuse reproduction package: M fine-tuned instances of one
+architecture merged into one (M, B) serving grid."""

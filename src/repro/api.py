@@ -17,6 +17,8 @@ Batch layout per family (see DESIGN.md §5):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -36,6 +38,44 @@ def family_module(cfg: ModelConfig):
 
 def init(cfg, key):
     return family_module(cfg).init(cfg, key)
+
+
+def init_instance(cfg, key, i):
+    """Instance ``i`` of a merged grid, alone: ``init`` of the
+    one-instance config under ``fold_in(key, i)`` — the same model
+    whatever the grid's M is.  One compiled program serves every i."""
+    return _init_one(cfg.with_(num_instances=1), key, i)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_one(cfg, key, i):
+    return init(cfg, jax.random.fold_in(key, i))
+
+
+def init_instances(cfg, key, *, shardings=None):
+    """The merged grid of ``cfg.num_instances`` instances, instance i
+    being :func:`init_instance` (drawn at f32, held at
+    ``cfg.param_dtype``).  Each instance is written into the grid as it
+    is drawn and then released, so no second full copy of the weights
+    ever exists.  ``shardings``: optional per-leaf shardings of the grid
+    (a mesh-sharded grid is then built in place)."""
+    ax = axes(cfg)
+    master = cfg.with_(param_dtype="float32")
+    grid = jax.jit(lambda: jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype), abstract_params(cfg)),
+        out_shardings=shardings)()
+
+    def put(grid, inst, i):
+        return jax.tree.map(
+            lambda a, g, w: jax.lax.dynamic_update_slice_in_dim(
+                g, w.astype(g.dtype), i, axis=a.index("instances")),
+            ax, grid, inst, is_leaf=lambda x: isinstance(x, tuple))
+
+    put = jax.jit(put, out_shardings=shardings, donate_argnums=(
+        (0,) if jax.default_backend() != "cpu" else ()))
+    for i in range(cfg.num_instances):
+        grid = put(grid, init_instance(master, key, i), i)
+    return grid
 
 
 def abstract_params(cfg):

@@ -64,8 +64,8 @@ class ModelConfig:
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     remat: bool = True                # activation checkpointing in train_step
-    # route supported blocks through the Pallas kernels (interpret=True on
-    # CPU, Mosaic on TPU) — forward/serving paths; training keeps the XLA
+    # route supported blocks through the Pallas kernels (the interpreter
+    # on CPU, Mosaic on TPU) — forward/serving paths; training keeps the XLA
     # scan (pallas_call has no registered VJP).  Off by default: the
     # dry-run rooflines stay pure-XLA so §Perf deltas are attributable.
     use_pallas_kernels: bool = False

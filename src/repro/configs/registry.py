@@ -52,6 +52,17 @@ def get_smoke_config(arch: str) -> ModelConfig:
     return _module(arch).smoke_config()
 
 
+def inference_config(cfg: ModelConfig) -> ModelConfig:
+    """Inference deployments carry bf16 weights (f32 masters are a
+    training-only concern)."""
+    return cfg.with_(param_dtype="bfloat16")
+
+
+def serving_config(arch: str) -> ModelConfig:
+    """The published config as the serving path holds it."""
+    return inference_config(get_config(arch))
+
+
 def supported(arch: str, shape: ShapeConfig | str) -> bool:
     shape = SHAPES[shape] if isinstance(shape, str) else shape
     cfg = get_config(arch)
@@ -70,9 +81,7 @@ def config_for_shape(arch: str, shape: ShapeConfig | str, *, num_instances: int 
     if shape.name == "long_500k" and cfg.family in ("dense", "moe", "vlm"):
         cfg = cfg.with_(sliding_window=LONG_CONTEXT_WINDOW)
     if shape.kind in ("prefill", "decode"):
-        # inference deployments carry bf16 weights (f32 masters are a
-        # training-only concern)
-        cfg = cfg.with_(param_dtype="bfloat16")
+        cfg = inference_config(cfg)
     if num_instances != 1:
         cfg = cfg.with_(num_instances=num_instances)
     return cfg

@@ -5,8 +5,10 @@ attends a C-token query block over ``[cache-before-chunk, chunk]``.
 This extends ``decode_attn.py`` from q-len 1 to q-len C — the cache's S
 axis streams through VMEM in blocks as the innermost grid axis, online-
 softmax running (max, sum, acc) state lives in VMEM scratch across
-S-steps (grid revisiting pattern), and the per-instance q tile
-(C·G x hd) is resident the whole time.
+S-steps (grid revisiting pattern), and the lane's chunk queries (C x
+H*hd) are resident the whole time.  As in ``decode_attn.py``, blocks
+keep every head (q as (C, H*hd), the cache as (S-block, KVH*hd)) and
+heads are separated in-kernel by static lane slices.
 
 Masking is ARITHMETIC, driven by the scalar-prefetched per-lane offsets
 (the absolute position of each lane's first chunk token): slot j of a
@@ -18,7 +20,7 @@ covers causality, the sliding window, ring validity and the attention
 sink, so the dense O((S+C)·C) position/mask tensors the XLA path
 materializes per layer never exist here.
 
-Grid: (M, B, KVH, T/bs) with T = S_cache + C.
+Grid: (M, B, T/bs) with T = S_cache + C.
 """
 from __future__ import annotations
 
@@ -28,15 +30,18 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
+from repro.kernels.decode_attn import seq_block
 
 NEG_INF = -1e30
 
 
 def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            ns: int, bs: int, c: int, g: int, hd: int, s_cache: int,
+            ns: int, bs: int, c: int, h: int, g: int, hd: int, s_cache: int,
             pin: int, window: int, sink: int, causal: bool):
-    mi, bi, si = pl.program_id(0), pl.program_id(1), pl.program_id(3)
-    cg = c * g
+    mi, bi, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(si == 0)
     def _init():
@@ -44,16 +49,14 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, :, 0].astype(jnp.float32).reshape(cg, hd)   # (C·G, hd)
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)                   # (bs, hd)
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                         # (C, H*hd)
+    k = k_ref[0, 0].astype(jnp.float32)                         # (bs, KVH*hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
-    s = jnp.dot(q, k.T) / math.sqrt(hd)                         # (C·G, bs)
-
-    # positions from the lane offset alone (rows are C-major over G)
+    # positions from the lane offset alone
     off = off_ref[mi, bi]
-    ci = jax.lax.broadcasted_iota(jnp.int32, (cg, bs), 0) // g
-    slot = si * bs + jax.lax.broadcasted_iota(jnp.int32, (cg, bs), 1)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (c, bs), 0)
+    slot = si * bs + jax.lax.broadcasted_iota(jnp.int32, (c, bs), 1)
     q_pos = off + ci
     # cache slots: pinned prefix + ring over positions >= pin
     # (== layers.cache_positions_after(off - 1, s_cache, pin))
@@ -81,33 +84,28 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if sink > 0:
             in_win = in_win | (p < sink)
         valid = valid & in_win
-    s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]                                         # (C·G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    pexp = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + pexp.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(pexp, v)
-    m_ref[...] = m_new
+    for hi in range(h):                      # static loop over q heads
+        kh = hi // g
+        qh = q[:, hi * hd:(hi + 1) * hd]                        # (C, hd)
+        kv_cols = slice(kh * hd, (kh + 1) * hd)
+        s = jnp.dot(qh, k[:, kv_cols].T)
+        s = jnp.where(valid, s / math.sqrt(hd), NEG_INF)         # (C, bs)
+        m_prev = m_ref[hi]                                      # (C, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[hi] = l_ref[hi] * corr + pexp.sum(axis=-1, keepdims=True)
+        acc_ref[hi] = acc_ref[hi] * corr + jnp.dot(
+            pexp, v[:, kv_cols])
+        m_ref[hi] = m_new
 
     @pl.when(si == ns - 1)
     def _done():
-        o_ref[0, 0, :, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).reshape(c, g, hd).astype(o_ref.dtype)
-
-
-def _clamp(block: int, dim: int) -> int:
-    b = min(block, dim)
-    while dim % b:
-        b -= 1
-    return b
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
+        for hi in range(h):
+            o_ref[0, 0, :, hi * hd:(hi + 1) * hd] = (
+                acc_ref[hi] / jnp.maximum(l_ref[hi], 1e-30)
+            ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -124,7 +122,7 @@ def chunk_prefill_attention(
     sink: int = 0,
     causal: bool = True,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (M,B,C,H,hd); k,v: (M,B,T,KVH,hd) with T = s_cache + C — the
     pre-chunk cache concatenated with the chunk's own k/v; offset: (M,B)
@@ -133,42 +131,33 @@ def chunk_prefill_attention(
     m, b, c, h, hd = q.shape
     t, kvh = k.shape[2], k.shape[3]
     assert t == s_cache + c, (t, s_cache, c)
-    g = h // kvh
-    bs = _clamp(block_s, t)
+    bs = seq_block(block_s, t)
     ns = t // bs
-    grid = (m, b, kvh, ns)
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    qg = q.reshape(m, b, c, kvh, g, hd)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, c, 1, g, hd),
-                         lambda mi, bi, ki, si, off: (mi, bi, 0, ki, 0, 0)),
-            pl.BlockSpec((1, 1, bs, 1, hd),
-                         lambda mi, bi, ki, si, off: (mi, bi, si, ki, 0)),
-            pl.BlockSpec((1, 1, bs, 1, hd),
-                         lambda mi, bi, ki, si, off: (mi, bi, si, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, c, 1, g, hd),
-                               lambda mi, bi, ki, si, off: (mi, bi, 0, ki, 0, 0)),
-        scratch_shapes=[
-            _vmem((c * g, 1), jnp.float32),
-            _vmem((c * g, 1), jnp.float32),
-            _vmem((c * g, hd), jnp.float32),
-        ],
-    )
+    q_spec = pl.BlockSpec((1, 1, c, h * hd),
+                          lambda mi, bi, si, off: (mi, bi, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, bs, kvh * hd),
+                           lambda mi, bi, si, off: (mi, bi, si, 0))
     out = pl.pallas_call(
         functools.partial(
-            _kernel, ns=ns, bs=bs, c=c, g=g, hd=hd, s_cache=s_cache,
-            pin=pin, window=window, sink=sink, causal=causal,
+            _kernel, ns=ns, bs=bs, c=c, h=h, g=h // kvh, hd=hd,
+            s_cache=s_cache, pin=pin, window=window, sink=sink,
+            causal=causal,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, b, c, kvh, g, hd), q.dtype),
-        interpret=interpret,
-    )(offset.astype(jnp.int32), qg, k, v)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m, b, ns),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((h, c, 1), jnp.float32),
+                pltpu.VMEM((h, c, 1), jnp.float32),
+                pltpu.VMEM((h, c, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, b, c, h * hd), q.dtype),
+        interpret=interpret_mode(interpret),
+    )(offset.astype(jnp.int32), q.reshape(m, b, c, h * hd),
+      k.reshape(m, b, t, kvh * hd), v.reshape(m, b, t, kvh * hd))
     return out.reshape(m, b, c, h, hd)
 
 
@@ -192,7 +181,6 @@ def chunk_prefill_attention_sharded(
     back to the plain (GSPMD-partitioned) call when KVH doesn't divide
     the model axis.
     """
-    from repro.launch.compat import shard_map
 
     m, b, c, h, hd = q.shape
     t, kvh = k.shape[2], k.shape[3]
@@ -205,7 +193,7 @@ def chunk_prefill_attention_sharded(
     kv_spec = rules.spec(("instances", "batch", None, "kv_heads", None),
                          (m, b, t, kvh, hd))
     off_spec = rules.spec(("instances", "batch"), (m, b))
-    return shard_map(
+    return jax.shard_map(
         lambda ql, kl, vl, ol: chunk_prefill_attention(ql, kl, vl, ol, **kw),
         mesh=rules.mesh,
         in_specs=(q_spec, kv_spec, kv_spec, off_spec),

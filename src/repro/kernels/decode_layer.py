@@ -32,7 +32,7 @@ sharded ffn) cannot live inside one kernel, so the layer splits into an
 attention-phase kernel and an FFN-phase kernel with a psum after each —
 2 launches + 2 collectives per layer per rank.
 
-Everything is validated with ``interpret=True`` on CPU (see ops.py); at
+Everything is validated in the Pallas interpreter on CPU (see ops.py); at
 smoke/serving shapes the per-lane weights fit VMEM outright — see
 DESIGN.md §6.7 for the VMEM budget per block shape and the ff/V blocking
 a full-size TPU variant needs.
@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -86,7 +88,7 @@ def _rope_rows(x, pos, theta):
     ``layers.rope`` (f32 angles, cos/sin cast to x.dtype)."""
     hd = x.shape[-1]
     half = hd // 2
-    i = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
+    i = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(jnp.float32)
     freqs = jnp.exp(-math.log(theta) * i / half)
     ang = pos.astype(jnp.float32) * freqs
     cos = jnp.cos(ang).astype(x.dtype)
@@ -159,16 +161,16 @@ def _layer_kernel(pos_ref, *refs, h, kvh, hd, eps, theta, window, has_bias,
     pos = pos_ref[mi, bi]
     s_cache = ck_ref.shape[2]
     g = h // kvh
-    x = x_ref[0]                                            # (1, D)
+    x = x_ref[0, 0]                                         # (1, D)
 
-    n = _rms(x, an_ref[...], eps)
+    n = _rms(x, an_ref[0], eps)
     q = jnp.dot(n, wq_ref[0])                               # (1, H*hd)
     k = jnp.dot(n, wk_ref[0])
     v = jnp.dot(n, wv_ref[0])
     if has_bias:
-        q = q + bq_ref[...].astype(q.dtype)
-        k = k + bk_ref[...].astype(k.dtype)
-        v = v + bv_ref[...].astype(v.dtype)
+        q = q + bq_ref[0].astype(q.dtype)
+        k = k + bk_ref[0].astype(k.dtype)
+        v = v + bv_ref[0].astype(v.dtype)
     qh = q.reshape(h, hd)
     kh = k.reshape(kvh, hd)
     vh = v.reshape(kvh, hd)
@@ -190,21 +192,21 @@ def _layer_kernel(pos_ref, *refs, h, kvh, hd, eps, theta, window, has_bias,
                 out_dtype=x.dtype)
     attn = jnp.dot(o, wo_ref[0])                            # (1, D)
     if phase == "attn":
-        out_ref[0] = attn                                   # pre-psum partial
+        out_ref[0, 0] = attn                                # pre-psum partial
         return
     x2 = x + attn
-    n2 = _rms(x2, mn_ref[...], eps)
+    n2 = _rms(x2, mn_ref[0], eps)
     hm = jax.nn.silu(jnp.dot(n2, wg_ref[0])) * jnp.dot(n2, wu_ref[0])
-    out_ref[0] = x2 + jnp.dot(hm, wd_ref[0])
+    out_ref[0, 0] = x2 + jnp.dot(hm, wd_ref[0])
 
 
 def _ffn_kernel(x_ref, mn_ref, wg_ref, wu_ref, wd_ref, o_ref, *, eps):
     """FFN phase of the sharded variant: rms(mlp_norm) + SwiGLU over the
     rank-local ff slice; the down-proj output is a pre-psum partial."""
-    x = x_ref[0]
-    n2 = _rms(x, mn_ref[...], eps)
+    x = x_ref[0, 0]
+    n2 = _rms(x, mn_ref[0], eps)
     hm = jax.nn.silu(jnp.dot(n2, wg_ref[0])) * jnp.dot(n2, wu_ref[0])
-    o_ref[0] = jnp.dot(hm, wd_ref[0])
+    o_ref[0, 0] = jnp.dot(hm, wd_ref[0])
 
 
 def _layer_call(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
@@ -214,38 +216,41 @@ def _layer_call(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
     h, hd = num_heads, head_dim
     has_bias = "bq" in lp
 
-    row = lambda mi, bi, pr: (mi, 0)
+    # vectors get a singleton row dim ((M, 1, n) scales and biases, an
+    # (M, B, 1, D) residual) so every block's last two dims equal the
+    # array's, as the TPU's (8, 128) tiling rule demands
+    row = lambda a: a.reshape(m, 1, a.shape[-1])
     mat = lambda mi, bi, pr: (mi, 0, 0)
-    lane3 = lambda mi, bi, pr: (mi, bi, 0)
+    lane4 = lambda mi, bi, pr: (mi, bi, 0, 0)
     lane5 = lambda mi, bi, pr: (mi, bi, 0, 0, 0)
     cache_spec = pl.BlockSpec((1, 1, s_cache, kvh, hd), lane5)
+    row_spec = lambda n: pl.BlockSpec((1, 1, n), mat)
+    x_spec = pl.BlockSpec((1, 1, 1, d), lane4)
 
     in_specs = [
-        pl.BlockSpec((1, 1, d), lane3),
-        pl.BlockSpec((1, d), row),
+        x_spec,
+        row_spec(d),
         pl.BlockSpec((1, d, h * hd), mat),
         pl.BlockSpec((1, d, kvh * hd), mat),
         pl.BlockSpec((1, d, kvh * hd), mat),
     ]
-    ops = [x, lp["attn_norm"], lp["wq"], lp["wk"], lp["wv"]]
+    ops = [x.reshape(m, b, 1, d), row(lp["attn_norm"]), lp["wq"], lp["wk"],
+           lp["wv"]]
     if has_bias:
-        in_specs += [
-            pl.BlockSpec((1, h * hd), row),
-            pl.BlockSpec((1, kvh * hd), row),
-            pl.BlockSpec((1, kvh * hd), row),
-        ]
-        ops += [lp["bq"], lp["bk"], lp["bv"]]
+        in_specs += [row_spec(h * hd), row_spec(kvh * hd),
+                     row_spec(kvh * hd)]
+        ops += [row(lp["bq"]), row(lp["bk"]), row(lp["bv"])]
     in_specs.append(pl.BlockSpec((1, h * hd, d), mat))
     ops.append(lp["wo"])
     if phase == "full":
         ff = lp["w_gate"].shape[2]
         in_specs += [
-            pl.BlockSpec((1, d), row),
+            row_spec(d),
             pl.BlockSpec((1, d, ff), mat),
             pl.BlockSpec((1, d, ff), mat),
             pl.BlockSpec((1, ff, d), mat),
         ]
-        ops += [lp["mlp_norm"], lp["w_gate"], lp["w_up"], lp["w_down"]]
+        ops += [row(lp["mlp_norm"]), lp["w_gate"], lp["w_up"], lp["w_down"]]
     in_specs += [cache_spec, cache_spec]
     ops += [ck, cv]
 
@@ -261,27 +266,24 @@ def _layer_call(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
             num_scalar_prefetch=1,
             grid=(m, b),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, d), lane3),
-                cache_spec,
-                cache_spec,
-            ],
+            out_specs=[x_spec, cache_spec, cache_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((m, b, d), x.dtype),
+            jax.ShapeDtypeStruct((m, b, 1, d), x.dtype),
             jax.ShapeDtypeStruct(ck.shape, ck.dtype),
             jax.ShapeDtypeStruct(cv.shape, cv.dtype),
         ],
         input_output_aliases={n_in - 2: 1, n_in - 1: 2},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(pos.astype(jnp.int32), *ops)
-    return out, k_out, v_out
+    return out.reshape(m, b, d), k_out, v_out
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_heads", "head_dim", "rope_theta", "window", "eps", "interpret"))
 def decode_layer(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
-                 window: int = 0, eps: float = 1e-5, interpret: bool = True):
+                 window: int = 0, eps: float = 1e-5,
+                 interpret: bool | None = None):
     """One fused dense decode layer for the whole (M, B) grid.
 
     lp: the dense layer param dict (attn_norm, wq/wk/wv[+bq/bk/bv], wo,
@@ -299,26 +301,26 @@ def decode_layer(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def _ffn_call(x, mlp_norm, w_gate, w_up, w_down, *, eps, interpret):
+def _ffn_call(x, mlp_norm, w_gate, w_up, w_down, *, eps, interpret=None):
     m, b, d = x.shape
     ff = w_gate.shape[2]
-    row = lambda mi, bi: (mi, 0)
     mat = lambda mi, bi: (mi, 0, 0)
-    lane3 = lambda mi, bi: (mi, bi, 0)
+    x_spec = pl.BlockSpec((1, 1, 1, d), lambda mi, bi: (mi, bi, 0, 0))
     return pl.pallas_call(
         functools.partial(_ffn_kernel, eps=eps),
         grid=(m, b),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lane3),
-            pl.BlockSpec((1, d), row),
+            x_spec,
+            pl.BlockSpec((1, 1, d), mat),
             pl.BlockSpec((1, d, ff), mat),
             pl.BlockSpec((1, d, ff), mat),
             pl.BlockSpec((1, ff, d), mat),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lane3),
-        out_shape=jax.ShapeDtypeStruct((m, b, d), x.dtype),
-        interpret=interpret,
-    )(x, mlp_norm, w_gate, w_up, w_down)
+        out_specs=x_spec,
+        out_shape=jax.ShapeDtypeStruct((m, b, 1, d), x.dtype),
+        interpret=interpret_mode(interpret),
+    )(x.reshape(m, b, 1, d), mlp_norm.reshape(m, 1, d), w_gate, w_up,
+      w_down).reshape(m, b, d)
 
 
 def decode_layer_sharded(lp, x, ck, cv, pos, *, rules, num_heads, head_dim,
@@ -335,8 +337,6 @@ def decode_layer_sharded(lp, x, ck, cv, pos, *, rules, num_heads, head_dim,
     cache-out shape, so non-dividing kv heads (and a non-dividing ffn)
     fall back to the unsharded megakernel.
     """
-    from repro.launch.compat import shard_map
-
     m, b, d = x.shape
     kvh = ck.shape[3]
     h, hd = num_heads, head_dim
@@ -354,7 +354,7 @@ def decode_layer_sharded(lp, x, ck, cv, pos, *, rules, num_heads, head_dim,
         rep = lambda a: rules.spec(
             ("instances",) + (None,) * (a.ndim - 1), a.shape)
         lp_specs = {kk: rep(a) for kk, a in lp.items()}
-        return shard_map(
+        return jax.shard_map(
             lambda lp_l, x_l, ck_l, cv_l, pos_l: decode_layer(
                 lp_l, x_l, ck_l, cv_l, pos_l, num_heads=num_heads,
                 head_dim=hd, rope_theta=rope_theta, window=window, eps=eps,
@@ -397,7 +397,7 @@ def decode_layer_sharded(lp, x, ck, cv, pos, *, rules, num_heads, head_dim,
             lp_l["w_down"], eps=eps, **kw)
         return x2 + jax.lax.psum(down, model_ax), nk, nv
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=rules.mesh,
         in_specs=({kk: specs[kk] for kk in lp_in}, x_spec, cache_spec,
                   cache_spec, pos_spec),
@@ -412,18 +412,22 @@ def decode_layer_sharded(lp, x, ck, cv, pos, *, rules, num_heads, head_dim,
 
 
 def _logits_kernel(x_ref, sc_ref, hd_ref, tok_ref, val_out_ref, val_ref,
-                   idx_ref, *, eps, nv, bv):
-    vi = pl.program_id(2)
+                   idx_ref, *, eps, nv, bv, v):
+    vi = pl.program_id(1)
 
     @pl.when(vi == 0)
     def _init():
         val_ref[...] = jnp.full_like(val_ref, NEG_INF)
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
-    n = _rms(x_ref[0], sc_ref[...], eps)                    # (1, D)
-    logits = jnp.dot(n.astype(jnp.float32), hd_ref[0].astype(jnp.float32))
-    bm = logits.max(axis=-1, keepdims=True)                 # (1, 1)
+    n = _rms(x_ref[0], sc_ref[0], eps)                      # (B, D)
+    logits = jnp.dot(n.astype(jnp.float32), hd_ref[0].astype(jnp.float32),
+                     preferred_element_type=jnp.float32)   # (B, bv)
     ii = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    if v % bv:
+        # the last vocab block overhangs V: its tail columns are padding
+        logits = jnp.where(vi * bv + ii < v, logits, NEG_INF)
+    bm = logits.max(axis=-1, keepdims=True)                 # (B, 1)
     first = jnp.where(logits == bm, ii, jnp.int32(2**31 - 1)).min(
         axis=-1, keepdims=True)
     # strict > keeps the earliest block's max; within a block ``first``
@@ -434,49 +438,45 @@ def _logits_kernel(x_ref, sc_ref, hd_ref, tok_ref, val_out_ref, val_ref,
 
     @pl.when(vi == nv - 1)
     def _done():
-        tok_ref[0, 0] = idx_ref[0, 0]
-        val_out_ref[0, 0] = val_ref[0, 0]
-
-
-def _clamp(block: int, dim: int) -> int:
-    b = min(block, dim)
-    while dim % b:
-        b -= 1
-    return b
+        tok_ref[0] = idx_ref[...]
+        val_out_ref[0] = val_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_v", "interpret"))
 def _logits_argmax_parts(x, scale, head, *, eps: float = 1e-5,
-                         block_v: int = 2048, interpret: bool = True):
+                         block_v: int = 1024, interpret: bool | None = None):
     """Returns (tok (M,B) int32, val (M,B) f32): the greedy argmax and
-    its logit value (the value feeds the sharded cross-rank combine)."""
+    its logit value (the value feeds the sharded cross-rank combine).
+
+    Grid (M, V/bv): one program multiplies an instance's B lanes by a
+    (D, bv) vocab block of its head.  ``block_v`` is a multiple of 128
+    (the TPU lane tile); a vocab that it does not divide gets a padded,
+    masked last block."""
     m, b, d = x.shape
     v = head.shape[2]
-    bv = _clamp(block_v, v)
-    nv = v // bv
+    bv = v if v <= block_v else block_v
+    nv = pl.cdiv(v, bv)
+    lane_rows = pl.BlockSpec((1, b, 1), lambda mi, vi: (mi, 0, 0))
     tok, val = pl.pallas_call(
-        functools.partial(_logits_kernel, eps=eps, nv=nv, bv=bv),
-        grid=(m, b, nv),
+        functools.partial(_logits_kernel, eps=eps, nv=nv, bv=bv, v=v),
+        grid=(m, nv),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda mi, bi, vi: (mi, bi, 0)),
-            pl.BlockSpec((1, d), lambda mi, bi, vi: (mi, 0)),
-            pl.BlockSpec((1, d, bv), lambda mi, bi, vi: (mi, 0, vi)),
+            pl.BlockSpec((1, b, d), lambda mi, vi: (mi, 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda mi, vi: (mi, 0, 0)),
+            pl.BlockSpec((1, d, bv), lambda mi, vi: (mi, 0, vi)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda mi, bi, vi: (mi, bi)),
-            pl.BlockSpec((1, 1), lambda mi, bi, vi: (mi, bi)),
-        ],
+        out_specs=[lane_rows, lane_rows],
         out_shape=[
-            jax.ShapeDtypeStruct((m, b), jnp.int32),
-            jax.ShapeDtypeStruct((m, b), jnp.float32),
+            jax.ShapeDtypeStruct((m, b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m, b, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.int32),
+            pltpu.VMEM((b, 1), jnp.float32),
+            pltpu.VMEM((b, 1), jnp.int32),
         ],
-        interpret=interpret,
-    )(x, scale, head)
-    return tok, val
+        interpret=interpret_mode(interpret),
+    )(x, scale.reshape(m, 1, d), head)
+    return tok[..., 0], val[..., 0]
 
 
 def logits_sample(x, scale, head, *, eps: float = 1e-5, **kw):
@@ -496,8 +496,6 @@ def logits_sample_sharded(x, scale, head, *, rules, eps: float = 1e-5, **kw):
     """``logits_sample`` under shard_map: vocab slices ride "model", each
     rank computes its local (max, argmax) in the kernel, and a tiny
     all-gather picks the global first-occurrence argmax."""
-    from repro.launch.compat import shard_map
-
     m, b, d = x.shape
     v = head.shape[2]
     ax = rules.mapping.get("vocab")
@@ -509,7 +507,7 @@ def logits_sample_sharded(x, scale, head, *, rules, eps: float = 1e-5, **kw):
         # data-local fallback — a bare pallas_call under GSPMD splits
         # the grid out from under the kernel's program-id indexing
         head_rep = rules.spec(("instances", None, None), head.shape)
-        return shard_map(
+        return jax.shard_map(
             lambda x_l, sc_l, hd_l: logits_sample(x_l, sc_l, hd_l, eps=eps,
                                                   **kw),
             mesh=rules.mesh,
@@ -528,7 +526,7 @@ def logits_sample_sharded(x, scale, head, *, rules, eps: float = 1e-5, **kw):
         cand = jnp.where(vals == best, toks, jnp.int32(2**31 - 1))
         return cand.min(axis=0).astype(jnp.int32)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=rules.mesh,
         in_specs=(x_spec, sc_spec, head_spec),
         out_specs=out_spec, check_vma=False,

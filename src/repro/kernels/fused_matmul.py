@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     k = pl.program_id(3)
@@ -77,8 +79,6 @@ def fused_matmul(
 
     ``interpret=None`` auto-detects: compiled Mosaic on TPU, Pallas
     interpreter elsewhere (kernel bodies execute on CPU for tests)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     m, t, d = x.shape
     f = w.shape[2]
     bt, bf, bd = _clamp(block_t, t), _clamp(block_f, f), _clamp(block_d, d)
@@ -97,7 +97,7 @@ def fused_matmul(
             out_specs=o_spec,
             out_shape=jax.ShapeDtypeStruct((m, t, f), x.dtype),
             scratch_shapes=[pltpu_scratch(bt, bf)],
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(x, w)
     b_spec = pl.BlockSpec((1, bf), lambda mi, ti, fi, ki: (mi, fi))
     return pl.pallas_call(
@@ -107,7 +107,7 @@ def fused_matmul(
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((m, t, f), x.dtype),
         scratch_shapes=[pltpu_scratch(bt, bf)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w, b)
 
 
@@ -136,7 +136,6 @@ def fused_matmul_sharded(
     divide their mesh axes replicate via the rules' divisibility guard,
     so any shape is accepted.
     """
-    from repro.launch.compat import shard_map
 
     m, t, d = x.shape
     f = w.shape[2]
@@ -145,13 +144,13 @@ def fused_matmul_sharded(
     o_spec = rules.spec(("instances", None, "mlp"), (m, t, f))
 
     if b is None:
-        return shard_map(
+        return jax.shard_map(
             lambda xl, wl: fused_matmul(xl, wl, **kw),
             mesh=rules.mesh, in_specs=(x_spec, w_spec), out_specs=o_spec,
             check_vma=False,
         )(x, w)
     b_spec = rules.spec(("instances", "mlp"), b.shape)
-    return shard_map(
+    return jax.shard_map(
         lambda xl, wl, bl: fused_matmul(xl, wl, bl, **kw),
         mesh=rules.mesh, in_specs=(x_spec, w_spec, b_spec), out_specs=o_spec,
         check_vma=False,

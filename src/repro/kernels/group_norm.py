@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[0].astype(jnp.float32)                 # (bt, D)
@@ -35,7 +37,7 @@ def group_rms_norm(
     *,
     eps: float = 1e-5,
     block_t: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """x: (M,T,D), scale: (M,D) -> (M,T,D)."""
     m, t, d = x.shape
@@ -50,5 +52,5 @@ def group_rms_norm(
         ],
         out_specs=pl.BlockSpec((1, bt, d), lambda mi, ti: (mi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((m, t, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, scale)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(q_ref, k_ref, v_ref, lf_ref, li_ref,
             hs_ref, cf_ref, nf_ref, mf_ref,
@@ -100,7 +102,7 @@ def _clamp(block: int, dim: int) -> int:
 def mlstm_chunkwise(
     q: jax.Array, k: jax.Array, v: jax.Array,
     lf: jax.Array, li: jax.Array,
-    *, chunk: int = 128, interpret: bool = True,
+    *, chunk: int = 128, interpret: bool | None = None,
 ):
     """Chunkwise mLSTM from zero state.
 
@@ -137,6 +139,6 @@ def mlstm_chunkwise(
         ],
         out_shape=out_shape,
         scratch_shapes=[_vmem((bb, hd, hd)), _vmem((bb, hd)), _vmem((bb, 1))],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v, lf.astype(jnp.float32), li.astype(jnp.float32))
     return hs, (cf, nf, mf)

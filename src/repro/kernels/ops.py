@@ -1,19 +1,14 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On this container (CPU) kernels execute with ``interpret=True`` — the
-kernel body runs in Python per grid step, validating BlockSpec indexing
-and in-kernel math; on TPU (the target) set ``REPRO_PALLAS_INTERPRET=0``
-(or pass interpret=False) to compile real Mosaic kernels.  ``use_pallas``
+On a TPU the kernels compile with Mosaic; on any other backend they run
+in the Pallas interpreter (``repro.kernels.interpret_mode``), which
+validates BlockSpec indexing and in-kernel math.  ``use_pallas``
 gates whether the model zoo routes through the kernels or the plain-XLA
 reference path (default: reference — kernels are validated/benched
 explicitly, and the dry-run rooflines stay pure-XLA so the §Perf kernel
 deltas are attributable).
 """
 from __future__ import annotations
-
-import os
-
-import jax
 
 from repro.kernels import ref
 from repro.kernels.chunk_prefill_attn import (
@@ -32,13 +27,6 @@ from repro.kernels.group_norm import group_rms_norm as _group_rms_norm_pl
 from repro.kernels.slstm_cell import slstm_cell as _slstm_cell_pl
 
 
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 def fused_matmul(x, w, b=None, *, use_pallas: bool = True, rules=None, **kw):
     """``rules=`` (a models.common.Rules) runs the kernel under
     shard_map on the rules' mesh — instances data-parallel, output
@@ -46,14 +34,14 @@ def fused_matmul(x, w, b=None, *, use_pallas: bool = True, rules=None, **kw):
     if not use_pallas:
         return ref.fused_matmul(x, w, b)
     if rules is not None:
-        return _fused_matmul_sh(x, w, b, rules=rules, interpret=_interpret(), **kw)
-    return _fused_matmul_pl(x, w, b, interpret=_interpret(), **kw)
+        return _fused_matmul_sh(x, w, b, rules=rules, **kw)
+    return _fused_matmul_pl(x, w, b, **kw)
 
 
 def group_rms_norm(x, scale, *, eps: float = 1e-5, use_pallas: bool = True, **kw):
     if not use_pallas:
         return ref.group_rms_norm(x, scale, eps)
-    return _group_rms_norm_pl(x, scale, eps=eps, interpret=_interpret(), **kw)
+    return _group_rms_norm_pl(x, scale, eps=eps, **kw)
 
 
 def decode_attention(q, k, v, kv_len, *, use_pallas: bool = True, rules=None, **kw):
@@ -62,9 +50,8 @@ def decode_attention(q, k, v, kv_len, *, use_pallas: bool = True, rules=None, **
     if not use_pallas:
         return ref.decode_attention(q, k, v, kv_len)
     if rules is not None:
-        return _decode_attention_sh(q, k, v, kv_len, rules=rules,
-                                    interpret=_interpret(), **kw)
-    return _decode_attention_pl(q, k, v, kv_len, interpret=_interpret(), **kw)
+        return _decode_attention_sh(q, k, v, kv_len, rules=rules, **kw)
+    return _decode_attention_pl(q, k, v, kv_len, **kw)
 
 
 def decode_layer(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
@@ -81,12 +68,10 @@ def decode_layer(lp, x, ck, cv, pos, *, num_heads, head_dim, rope_theta,
     if rules is not None:
         return _decode_layer_sh(
             lp, x, ck, cv, pos, rules=rules, num_heads=num_heads,
-            head_dim=head_dim, rope_theta=rope_theta, window=window, eps=eps,
-            interpret=_interpret(), **kw)
+            head_dim=head_dim, rope_theta=rope_theta, window=window, eps=eps, **kw)
     return _decode_layer_pl(
         lp, x, ck, cv, pos, num_heads=num_heads, head_dim=head_dim,
-        rope_theta=rope_theta, window=window, eps=eps,
-        interpret=_interpret(), **kw)
+        rope_theta=rope_theta, window=window, eps=eps, **kw)
 
 
 def logits_sample(x, scale, head, *, eps: float = 1e-5,
@@ -97,10 +82,8 @@ def logits_sample(x, scale, head, *, eps: float = 1e-5,
     if not use_pallas:
         return ref.logits_sample(x, scale, head, eps=eps)
     if rules is not None:
-        return _logits_sample_sh(x, scale, head, rules=rules, eps=eps,
-                                 interpret=_interpret(), **kw)
-    return _logits_sample_pl(x, scale, head, eps=eps,
-                             interpret=_interpret(), **kw)
+        return _logits_sample_sh(x, scale, head, rules=rules, eps=eps, **kw)
+    return _logits_sample_pl(x, scale, head, eps=eps, **kw)
 
 
 def chunk_prefill_attention(q, k, v, offset, *, s_cache: int, pin: int = 0,
@@ -116,21 +99,19 @@ def chunk_prefill_attention(q, k, v, offset, *, s_cache: int, pin: int = 0,
     if rules is not None:
         return _chunk_prefill_sh(
             q, k, v, offset, rules=rules, s_cache=s_cache, pin=pin,
-            window=window, sink=sink, interpret=_interpret(), **kw)
+            window=window, sink=sink, **kw)
     return _chunk_prefill_pl(
-        q, k, v, offset, s_cache=s_cache, pin=pin, window=window, sink=sink,
-        interpret=_interpret(), **kw)
+        q, k, v, offset, s_cache=s_cache, pin=pin, window=window, sink=sink, **kw)
 
 
 def slstm_cell(pre, r, state, *, num_heads: int, use_pallas: bool = True, **kw):
     if not use_pallas:
         return ref.slstm_cell(pre, r, state, num_heads=num_heads)
-    return _slstm_cell_pl(pre, r, state, num_heads=num_heads,
-                          interpret=_interpret(), **kw)
+    return _slstm_cell_pl(pre, r, state, num_heads=num_heads, **kw)
 
 
 def mlstm_chunkwise(q, k, v, lf, li, *, use_pallas: bool = True, **kw):
     if not use_pallas:
         return ref.mlstm_chunkwise(q, k, v, lf, li, **kw)
     from repro.kernels.mlstm_chunk import mlstm_chunkwise as _pl
-    return _pl(q, k, v, lf, li, interpret=_interpret(), **kw)
+    return _pl(q, k, v, lf, li, **kw)

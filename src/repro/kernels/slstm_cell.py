@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(pre_ref, r_ref, c0_ref, n0_ref, h0_ref, m0_ref,
             hs_ref, cf_ref, nf_ref, hf_ref, mf_ref,
@@ -103,7 +105,7 @@ def slstm_cell(
     *,
     num_heads: int,
     chunk: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Full sLSTM scan.
 
@@ -147,7 +149,7 @@ def slstm_cell(
         ],
         out_shape=out_shape,
         scratch_shapes=[_vmem(b, hd) for _ in range(4)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(pre_h, r, st(c0), st(n0), st(h0), st(m0))
 
     unst = lambda x: x.reshape(m, b, d)

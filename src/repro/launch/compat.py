@@ -1,104 +1,48 @@
-"""Version shims over drifting JAX mesh APIs.
+"""Runtime set-up shared by every entry point: meshes, forced host
+devices and the persistent compile cache.
 
-The repo's execution layers (dryrun / train / serve / the serving
-engine) all activate a mesh with ``with set_mesh(mesh), rules:`` so that
-``jax.lax.with_sharding_constraint`` calls carrying bare PartitionSpecs
-(``models.common.constrain``) resolve against it.  The spelling of "make
-this mesh current" has drifted across JAX releases:
-
-* newest JAX exposes ``jax.set_mesh`` (setter AND context manager),
-* a range of releases had ``jax.sharding.use_mesh`` (context manager),
-* 0.4.x has neither — but ``Mesh`` itself is a context manager that
-  installs the resource env ``with_sharding_constraint`` reads.
-
-``set_mesh(mesh)`` below returns a context manager valid on all three.
-``install()`` additionally polyfills ``jax.set_mesh`` when the running
-JAX lacks it, so external callers (tests, notebooks) written against the
-modern spelling keep working; it is invoked from ``repro/__init__``.
-
-Only the CONTEXT-MANAGER form is supported by the fallback: always write
-``with set_mesh(mesh):`` (never a bare ``set_mesh(mesh)`` statement).
+Every mesh in the repo is built by :func:`make_host_mesh` with
+``AxisType.Auto`` axes.  ``jax.make_mesh`` defaults to ``Explicit``
+axes, under which ``with_sharding_constraint`` (``models.common.
+constrain``) asserts a layout instead of imposing one; the model zoo and
+the serving engine rely on the latter.
 """
 from __future__ import annotations
 
 import contextlib
 import os
+from pathlib import Path
 
 import jax
 
-_NATIVE_SET_MESH = getattr(jax, "set_mesh", None)
-_USE_MESH = getattr(jax.sharding, "use_mesh", None)
-try:
-    _NATIVE_SHARD_MAP = jax.shard_map
-except AttributeError:
-    _NATIVE_SHARD_MAP = None
+# <repo>/.jax_cache — a fixed path, so every run of this checkout finds
+# what an earlier run compiled (the path is part of the cache key)
+_REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def set_mesh(mesh):
-    """Context manager making ``mesh`` current, on any supported JAX."""
-    if _NATIVE_SET_MESH is not None:
-        return _NATIVE_SET_MESH(mesh)
-    if _USE_MESH is not None:
-        return _USE_MESH(mesh)
-    return mesh  # 0.4.x: Mesh is itself a resource-env context manager
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-
-def _vma_spelled_shard_map(raw):
-    """Adapt ``raw`` to the modern signature: the replication-check
-    keyword was renamed check_rep -> check_vma, and some releases ship a
-    top-level ``jax.shard_map`` that still spells it check_rep."""
-    try:
-        import inspect
-        has_vma = "check_vma" in inspect.signature(raw).parameters
-    except (TypeError, ValueError):  # pragma: no cover — C-accelerated sig
-        has_vma = True
-    if has_vma:
-        return raw
-
-    # keep the historical positional order — install() may put this over
-    # jax.shard_map, where third-party callers pass positionally
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None, *,
-                  check_vma=None, **kw):
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return raw(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    return shard_map
-
-
-if _NATIVE_SHARD_MAP is not None:
-    shard_map = _vma_spelled_shard_map(_NATIVE_SHARD_MAP)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    shard_map = _vma_spelled_shard_map(_exp_shard_map)
-
-
-def install() -> None:
-    """Polyfill the modern spellings onto the jax module when missing.
-
-    Installed once from ``repro/__init__``; module-dict assignment wins
-    over jax's deprecation ``__getattr__``, so ``jax.set_mesh`` /
-    ``jax.shard_map`` call sites (the model zoo, the test-suite) run
-    unmodified on every supported JAX.  Caveat: the ``jax.set_mesh``
-    polyfill supports only the ``with jax.set_mesh(mesh):`` context
-    form — a bare setter statement has no 0.4.x equivalent and would
-    silently not install the mesh (see module docstring)."""
-    if getattr(jax, "set_mesh", None) is None:
-        jax.set_mesh = set_mesh
-    if getattr(jax, "shard_map", None) is not shard_map:
-        jax.shard_map = shard_map
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache lives in ``<repo>/.jax_cache``
+    (listed in ``.gitignore``)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_REPO_CACHE_DIR))
+    return str(_REPO_CACHE_DIR)
 
 
 @contextlib.contextmanager
 def mesh_context(mesh, *ctxs):
-    """Enter ``set_mesh(mesh)`` plus any extra context managers (Rules).
+    """Enter ``jax.set_mesh(mesh)`` plus any extra context managers
+    (Rules).
 
     ``mesh`` may be None (no-op — the single-device path), so callers
     can hold ONE code path for mesh-parametric and plain execution."""
     with contextlib.ExitStack() as stack:
         if mesh is not None:
-            stack.enter_context(set_mesh(mesh))
+            stack.enter_context(jax.set_mesh(mesh))
         for c in ctxs:
             if c is not None:
                 stack.enter_context(c)
@@ -106,15 +50,20 @@ def mesh_context(mesh, *ctxs):
 
 
 def make_host_mesh(shape: tuple[int, ...] | None = None,
-                   axes: tuple[str, ...] = ("data", "model")):
-    """A ("data", "model") mesh over the visible devices.
+                   axes: tuple[str, ...] = ("data", "model"), *,
+                   devices=None):
+    """A mesh with ``Auto`` axes over ``devices`` (default: all visible).
 
-    ``shape=None`` puts every device on the data axis (pure DP serving);
+    ``shape=None`` puts every device on the first axis (pure DP serving);
     pass an explicit (data, model) shape to split off tensor parallelism.
     """
+    if devices is None:
+        devices = jax.devices()
     if shape is None:
-        shape = (jax.device_count(),) + (1,) * (len(axes) - 1)
-    return jax.make_mesh(shape, axes)
+        shape = (len(devices),) + (1,) * (len(axes) - 1)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def force_host_devices_from_argv(argv) -> int:

@@ -30,8 +30,7 @@ from repro import api
 from repro.configs.base import SHAPES
 from repro.configs import registry
 from repro.launch import hlo_analysis
-from repro.launch.compat import set_mesh
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, num_chips
+from repro.launch.mesh import chip_peaks, make_production_mesh, num_chips
 from repro.launch.shardings import (
     batch_shardings, dp_train_rules, moe_dp_compute, moe_ep_shmap,
     moments_rules, replicated, serve_rules, train_rules, tree_shardings,
@@ -246,7 +245,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             cfg = cfg.with_(mlstm_chunk=128)
         mesh = make_production_mesh(multi_pod=multi_pod)
         rules, opt_rules, micro = rules_for(mesh, shape.kind, tag, arch=arch)
-        with set_mesh(mesh), rules:
+        with jax.set_mesh(mesh), rules:
             fn, args, in_sh = build_lowerable(
                 cfg, shape, mesh, rules, opt_rules=opt_rules,
                 micro_override=micro,
@@ -257,12 +256,14 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             t_compile = time.perf_counter() - t0 - t_lower
 
         chips = num_chips(mesh)
+        peaks = chip_peaks("TPU v5 lite")   # the production mesh's chip
         n_total = count_params(api.abstract_params(cfg))
         txt = compiled.as_text()
         analysis = hlo_analysis.analyze_hlo_text(txt)
         terms = hlo_analysis.roofline_terms(
             analysis, chips=chips,
-            peak_flops=PEAK_FLOPS_BF16, hbm_bw=HBM_BW, ici_bw=ICI_BW,
+            peak_flops=peaks.flops_bf16, hbm_bw=peaks.hbm_bw,
+            ici_bw=peaks.ici_bw,
         )
         mf = model_flops(cfg, shape, n_total)
         # per-chip useful model flops for the useful-compute ratio

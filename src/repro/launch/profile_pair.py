@@ -16,7 +16,6 @@ from repro.configs.base import SHAPES
 from repro.configs import registry
 from repro.launch import hlo_analysis
 from repro.launch import dryrun as D
-from repro.launch.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 
 
@@ -34,7 +33,7 @@ def main():
     cfg = registry.config_for_shape(args.arch, shape, num_instances=args.num_instances)
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     rules, opt_rules, micro = D.rules_for(mesh, shape.kind, args.tag, arch=args.arch)
-    with set_mesh(mesh), rules:
+    with jax.set_mesh(mesh), rules:
         fn, fargs, in_sh = D.build_lowerable(
             cfg, shape, mesh, rules, opt_rules=opt_rules,
             micro_override=micro,

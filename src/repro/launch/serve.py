@@ -31,7 +31,8 @@ import time
 
 # --devices must win before the first jax backend init (the device
 # count locks there; importing jax below is still safe)
-from repro.launch.compat import force_host_devices_from_argv, mesh_from_args
+from repro.launch.compat import (
+    enable_compile_cache, force_host_devices_from_argv, mesh_from_args)
 
 force_host_devices_from_argv(sys.argv)
 
@@ -40,7 +41,6 @@ import jax
 
 from repro import api
 from repro.configs import registry
-from repro.models import common as C
 from repro.serving import MultiModelServer, Request, SERVABLE_FAMILIES
 from repro.serving.scheduler import POLICIES
 
@@ -115,7 +115,7 @@ async def _stream_clients(server, reqs, max_queue, args):
     results = await asyncio.gather(*(client(r) for r in reqs))
     await engine.aclose()
     _print_recovery(sup)
-    return [r for r in results if r.status == "ok"]
+    return results
 
 
 def _serve_http(server, args):
@@ -243,8 +243,10 @@ def main():
                          "events + metrics + scheduler depths + SLO state "
                          "to DIR/flight-NNNN.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    base = registry.get_smoke_config(args.arch) if args.smoke else registry.get_config(args.arch)
+    base = (registry.get_smoke_config(args.arch) if args.smoke
+            else registry.serving_config(args.arch))
     if base.family not in SERVABLE_FAMILIES:
         raise SystemExit(f"family {base.family!r} is not servable")
     max_context = args.max_context
@@ -258,18 +260,15 @@ def main():
     if args.pallas_kernels:
         base = base.with_(use_pallas_kernels=True)
     m = args.num_instances
-    cfg1 = base.with_(num_instances=1)
     cfg = base.with_(num_instances=m)
 
-    # M independently-"fine-tuned" instances (different random weights)
-    keys = jax.random.split(jax.random.PRNGKey(args.seed), m)
-    instances = [api.init(cfg1, k) for k in keys]
-
-    # the paper's offline merge (§4: once per model set, amortized)
+    # M independently-"fine-tuned" instances (different random weights),
+    # drawn straight into the merged grid (the paper's offline merge)
     t0 = time.perf_counter()
-    merged = C.merge_instances(instances, api.axes(cfg1))
-    jax.block_until_ready(jax.tree.leaves(merged)[0])
-    print(f"NetFuse merge of {m} instances: {(time.perf_counter()-t0)*1e3:.1f} ms")
+    merged = api.init_instances(cfg, jax.random.PRNGKey(args.seed))
+    jax.block_until_ready(merged)
+    print(f"NetFuse merged grid of {m} instances built in "
+          f"{(time.perf_counter()-t0)*1e3:.1f} ms")
 
     mesh = mesh_from_args(args.devices, args.mesh_shape)
     if mesh is not None:
@@ -330,6 +329,8 @@ def main():
             server.submit(r)
         results = server.run_until_drained()
     dt = time.perf_counter() - t0
+    failed = [r for r in results if r.status == "error"]
+    results = [r for r in results if r.status == "ok"]
     if args.trace_out:
         import json as _json
         server.tracer.stop()
@@ -360,6 +361,11 @@ def main():
     _print_obs(server)
     for r in results[:4]:
         print(f"  req {r.request_id} (instance {r.instance}): {r.tokens[:8]}...")
+    if failed:
+        for r in failed:
+            print(f"  req {r.request_id} (instance {r.instance}) failed: "
+                  f"{r.error}", file=sys.stderr)
+        raise SystemExit(f"{len(failed)} request(s) ended in error")
 
 
 if __name__ == "__main__":

@@ -78,12 +78,11 @@ def main():
         )
 
     if args.mesh:
-        from repro.launch.compat import set_mesh
+        from repro.launch.compat import make_host_mesh
         from repro.launch.shardings import train_rules
-        n = jax.device_count()
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
-        print(f"mesh=(data={n}, model=1); rules active (constrain/shard_map paths engaged)")
-        with set_mesh(mesh), train_rules(mesh):
+        mesh = make_host_mesh()
+        print(f"mesh=(data={mesh.size}, model=1); rules active (constrain/shard_map paths engaged)")
+        with jax.set_mesh(mesh), train_rules(mesh):
             state, losses = run()
     else:
         state, losses = run()

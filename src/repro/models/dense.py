@@ -297,17 +297,30 @@ def decode_step(cfg: ModelConfig, params, cache: KVCache, tokens, pos):
         return logits, new_cache
     positions = pos[..., None]
     window = cfg.sliding_window
+    # The layer loop CARRIES the stacked cache and writes each layer back
+    # in place, rather than scanning it in and out (a second full cache).
+    # It is carried with (KVH, hd) merged into one lane-dense dim: on a
+    # TPU a head_dim-64 minor dim pads to 128 lanes, so the loop would
+    # otherwise hold padded copies of the cache at twice its size.
+    layer_shape = cache.k.shape[1:]
+    flat = lambda a: a.reshape(a.shape[:-2] + (-1,))
 
-    def body(xc, xs):
-        lp, ck, cv = xs
-        out, new_cache = _attn_mlp(
-            cfg, lp, xc, positions, window=window, cache=(ck, cv), decode_pos=pos
+    def body(carry, lp):
+        xc, ks, vs, i = carry
+        out, (nk, nv) = _attn_mlp(
+            cfg, lp, xc, positions, window=window,
+            cache=(ks[i].reshape(layer_shape), vs[i].reshape(layer_shape)),
+            decode_pos=pos,
         )
-        return out, new_cache
+        ks = lax.dynamic_update_index_in_dim(ks, flat(nk), i, 0)
+        vs = lax.dynamic_update_index_in_dim(vs, flat(nv), i, 0)
+        return (out, ks, vs, i + 1), None
 
-    x, (nk, nv) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    (x, nk, nv, _), _ = lax.scan(
+        body, (x, flat(cache.k), flat(cache.v), 0), params["layers"])
     logits = _logits(cfg, params, x)[:, :, 0]
-    return logits, KVCache(k=nk, v=nv)
+    return logits, KVCache(k=nk.reshape(cache.k.shape),
+                           v=nv.reshape(cache.v.shape))
 
 
 def decode_step_sample(cfg: ModelConfig, params, cache: KVCache, tokens, pos):

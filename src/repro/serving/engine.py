@@ -46,8 +46,8 @@ run the WHOLE serving path — slot surgery, chunked prefill, the fused
 decode+sample step, metrics — under an explicit ``jax.sharding.Mesh``
 with the instances/batch axes data-parallel and heads/cache_seq tensor-
 parallel (the logical-axis rules in ``launch/shardings.py``).  Params
-and the grid cache are ``jax.device_put`` once at init with per-leaf
-``NamedSharding``; every jit traces under the mesh + rules context so
+are ``jax.device_put`` once at init and the grid cache is built in
+place, both with per-leaf ``NamedSharding``; every jit traces under the mesh + rules context so
 the model zoo's ``constrain`` calls and the shard-safe slot surgery
 (``models/common.tree_take_slot``/``tree_put_slot``) pin layouts — no
 host gathers anywhere in the steady state.  ``mesh=None`` (default) is
@@ -186,20 +186,16 @@ class MultiModelServer:
         self.chunk_budget = max(1, chunk_budget)
 
         self.params = params
-        self.cache = api.make_cache(cfg, self.m, self.b, max_context)
+        self.cache = self._fresh_cache()
         self._grid_shard = self._rep_shard = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.launch.shardings import tree_shardings
-            # per-leaf NamedSharding for params and the grid cache, then
-            # device_put ONCE — everything downstream consumes committed,
-            # rules-conformant arrays
+            # per-leaf NamedSharding for params, device_put ONCE —
+            # everything downstream consumes committed, rules-conformant
+            # arrays
             self.params = jax.device_put(
                 params, tree_shardings(self.rules, api.axes(cfg), params)
-            )
-            self.cache = jax.device_put(
-                self.cache,
-                tree_shardings(self.rules, api.cache_axes(cfg), self.cache),
             )
             self._grid_shard = NamedSharding(
                 mesh, self.rules.spec(("instances", "batch"), (self.m, self.b))
@@ -355,6 +351,20 @@ class MultiModelServer:
             _block_impl,
             donate_argnums=(1,) if self.prefill.donate else (),
         )
+
+    def _fresh_cache(self):
+        """An empty grid cache.  On a mesh it is built in place with its
+        per-leaf NamedSharding: built whole on one device first, it can
+        exceed that device's memory."""
+        make = lambda: api.make_cache(self.cfg, self.m, self.b,
+                                      self.max_context)
+        if self.mesh is None:
+            return make()
+        from repro.launch.shardings import tree_shardings
+        shardings = tree_shardings(self.rules, api.cache_axes(self.cfg),
+                                   jax.eval_shape(make))
+        with self._ctx():
+            return jax.jit(make, out_shardings=shardings)()
 
     def _ctx(self):
         """Mesh + rules context for every trace/dispatch (no-op without a
@@ -928,16 +938,10 @@ class MultiModelServer:
         self.slot_prefilling[:] = False
         self.prefill.reset()
         self.metrics.reset_queue_depths()
-        with self._ctx():
-            cache = api.make_cache(self.cfg, self.m, self.b,
-                                   self.max_context)
+        self.cache = self._fresh_cache()
         key = jax.random.PRNGKey(self._seed)
         if self.mesh is not None:
-            from repro.launch.shardings import tree_shardings
-            cache = jax.device_put(
-                cache, tree_shardings(self.rules, self._cache_ax, cache))
             key = jax.device_put(key, self._rep_shard)
-        self.cache = cache
         self._key = key
         return live
 

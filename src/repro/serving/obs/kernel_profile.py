@@ -4,8 +4,8 @@ The dry-run roofline (``launch/hlo_analysis.py``) predicts what each
 compiled program *should* cost from first-order FLOP/byte counts; this
 module closes the loop by **timing the actual kernels** at serving
 shapes and reporting achieved FLOP/s and bytes/s against the same
-roofline envelope (``launch/mesh.py`` peaks), so a block-shape tune or
-a kernel rewrite is a measured win, not a vibe.
+roofline envelope (``launch/mesh.py`` peaks of the device's kind), so a
+block-shape tune or a kernel rewrite is a measured win, not a vibe.
 
 Seven kernels — the fused serving hot spots:
 
@@ -38,7 +38,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.kernels import interpret_mode
+from repro.launch.mesh import chip_peaks
 
 KERNELS = ("fused_matmul", "decode_attn", "chunk_prefill_attn",
            "mlstm_chunk", "slstm_cell", "decode_layer", "logits_sample")
@@ -87,7 +88,7 @@ def _mk_fused_matmul(m, t, d, f, dtype):
     from repro.kernels.fused_matmul import fused_matmul
     x = jnp.ones((m, t, d), dtype)
     w = jnp.ones((m, d, f), dtype)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     return (lambda: fused_matmul(x, w, interpret=interpret),
             2.0 * m * t * d * f,
             _nbytes(x, w) + m * t * f * x.dtype.itemsize,
@@ -100,7 +101,7 @@ def _mk_decode_attn(m, b, h, kvh, hd, s, dtype):
     k = jnp.ones((m, b, s, kvh, hd), dtype)
     v = jnp.ones((m, b, s, kvh, hd), dtype)
     kv_len = jnp.full((m, b), s, jnp.int32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     return (lambda: decode_attention(q, k, v, kv_len, interpret=interpret),
             4.0 * m * b * h * s * hd,
             _nbytes(q, k, v) + q.size * q.dtype.itemsize,
@@ -114,7 +115,7 @@ def _mk_chunk_prefill_attn(m, b, c, h, kvh, hd, s_cache, dtype):
     k = jnp.ones((m, b, t, kvh, hd), dtype)
     v = jnp.ones((m, b, t, kvh, hd), dtype)
     offset = jnp.full((m, b), s_cache, jnp.int32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     return (lambda: chunk_prefill_attention(
                 q, k, v, offset, s_cache=s_cache, interpret=interpret),
             4.0 * m * b * c * h * t * hd,       # dense-equivalent
@@ -129,7 +130,7 @@ def _mk_mlstm_chunk(m, b, h, s, hd, chunk, dtype):
     v = jnp.ones((m, b, h, s, hd), dtype)
     lf = jnp.zeros((m, b, h, s), jnp.float32)
     li = jnp.zeros((m, b, h, s), jnp.float32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     # per chunk cs: intra-chunk qk^T + a.v (4 cs^2 hd) and inter-chunk
     # q@C + k^T v state update (4 cs hd^2) -> S * 4 hd (cs + hd)
     cs = min(chunk, s)
@@ -150,7 +151,7 @@ def _mk_slstm_cell(m, b, s, d, h, dtype):
              jnp.zeros((m, b, d), jnp.float32),
              jnp.zeros((m, b, d), dtype),
              jnp.zeros((m, b, d), jnp.float32))
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     # per step: 4 recurrent head matmuls (8 H hd^2) + ~16 D elementwise
     return (lambda: slstm_cell(pre, r, state, num_heads=h,
                                interpret=interpret),
@@ -176,7 +177,7 @@ def _mk_decode_layer(m, b, d, h, kvh, hd, s, ff, window, dtype):
     ck = jnp.zeros((m, b, s, kvh, hd), dtype)
     cv = jnp.zeros((m, b, s, kvh, hd), dtype)
     pos = jnp.full((m, b), s - 1, jnp.int32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     # per lane: qkv proj + attention over the full ring + out proj + swiglu
     flops = m * b * (2.0 * d * (h + 2 * kvh) * hd + 4.0 * h * hd * s
                      + 2.0 * h * hd * d + 6.0 * d * ff)
@@ -194,7 +195,7 @@ def _mk_logits_sample(m, b, d, v, dtype):
     x = jnp.ones((m, b, d), dtype)
     scale = jnp.ones((m, d), dtype)
     head = jnp.ones((m, d, v), dtype)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     return (lambda: logits_sample(x, scale, head, interpret=interpret),
             2.0 * m * b * d * v,
             _nbytes(x, scale, head) + m * b * 4,
@@ -213,12 +214,15 @@ _BUILDERS = {
 
 
 def profile_kernel(name: str, *, dtype: str = "bfloat16", repeats: int = 3,
-                   peak_flops: float = PEAK_FLOPS_BF16,
-                   hbm_bw: float = HBM_BW, **shape) -> dict:
+                   device_kind: str | None = None, **shape) -> dict:
     """Time one kernel at the given shape; returns achieved FLOP/s and
-    bytes/s against the roofline envelope.  The first (compile/trace)
+    bytes/s against the roofline envelope of ``device_kind``'s peaks
+    (default: the kind of ``jax.devices()[0]``; a kind without published
+    peaks raises KeyError).  The first (compile/trace)
     call is excluded; ``wall_s`` is the min of ``repeats`` settled
     calls (min, not mean: dispatch noise only ever adds time)."""
+    device_kind = device_kind or jax.devices()[0].device_kind
+    peaks = chip_peaks(device_kind)
     fn, flops, nbytes, shape_str, interpret = _BUILDERS[name](
         **shape, dtype=jnp.dtype(dtype))
     jax.block_until_ready(fn())              # compile + warmup
@@ -228,8 +232,8 @@ def profile_kernel(name: str, *, dtype: str = "bfloat16", repeats: int = 3,
         jax.block_until_ready(fn())
         wall = min(wall, time.perf_counter() - t0)
     intensity = flops / nbytes
-    t_compute = flops / peak_flops
-    t_memory = nbytes / hbm_bw
+    t_compute = flops / peaks.flops_bf16
+    t_memory = nbytes / peaks.hbm_bw
     roofline_flops = flops / max(t_compute, t_memory)
     achieved_flops = flops / wall
     return {
@@ -238,6 +242,7 @@ def profile_kernel(name: str, *, dtype: str = "bfloat16", repeats: int = 3,
         "dtype": str(dtype),
         "backend": jax.default_backend(),
         "interpret": interpret,
+        "peaks_of": device_kind,
         "wall_s": wall,
         "flops": flops,
         "bytes": nbytes,
@@ -252,14 +257,15 @@ def profile_kernel(name: str, *, dtype: str = "bfloat16", repeats: int = 3,
 
 def profile_serving_kernels(cfg, *, slots: int = 4, max_context: int = 128,
                             chunk: int = 32, prefill_lanes: int = 4,
-                            repeats: int = 3,
-                            kernels=KERNELS) -> list[dict]:
+                            repeats: int = 3, kernels=KERNELS,
+                            device_kind: str | None = None) -> list[dict]:
     """Profile every serving kernel at this config's shapes (the grid
     and admission geometry the engine actually launches)."""
     shapes = serving_shapes(cfg, slots=slots, max_context=max_context,
                             chunk=chunk, prefill_lanes=prefill_lanes)
     return [
-        profile_kernel(k, dtype=cfg.dtype, repeats=repeats, **shapes[k])
+        profile_kernel(k, dtype=cfg.dtype, repeats=repeats,
+                       device_kind=device_kind, **shapes[k])
         for k in kernels
     ]
 

@@ -7,23 +7,29 @@ stand-ins for ``given``/``settings``/``st`` from here (module-level
 too).  ``given`` marks the test as skipped; ``st`` strategies evaluate
 to inert placeholders so decorator arguments still build.
 """
-import os
-
-# jax 0.4.3x's CPU thunk runtime segfaults inside backend_compile once a
-# single process has accumulated enough compiled executables (reproducible
-# at test_serving_chunked.py scale, same crash with the repo diff stashed
-# — not our code).  The legacy runtime compiles everything cleanly, so
-# pin it for the whole suite.  Appended (not assigned) so CI's
-# --xla_force_host_platform_device_count survives; must run before the
-# first jax import in the test process, which conftest import order
-# guarantees.
-_xla_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_cpu_use_thunk_runtime" not in _xla_flags:
-    os.environ["XLA_FLAGS"] = (
-        _xla_flags + " --xla_cpu_use_thunk_runtime=false"
-    ).strip()
+import gc
 
 import pytest
+
+
+@pytest.fixture(autouse=True)
+def _stay_under_the_map_count_limit():
+    """XLA:CPU keeps memory mappings for every compiled program, and a
+    worker that holds the programs of a long test file crosses the
+    kernel's ``vm.max_map_count`` (65530 by default), after which XLA
+    segfaults inside compile.  Once a test leaves the process past a
+    quarter of that many mappings, drop JAX's compiled-program caches."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            n = sum(1 for _ in f)
+    except OSError:
+        return
+    if n > 16_000:
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
 
 
 class _StrategyStub:

@@ -1,7 +1,10 @@
 """Launch-layer tests: HLO cost model, sharding rules, input specs, and
 in-process lowering of every family on a 1x1 mesh (the 512-device meshes
 are exercised by launch/dryrun.py, which must own jax initialization)."""
+import os
 import re
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -185,11 +188,12 @@ SMALL_DECODE = ShapeConfig("small_decode", 64, 2, "decode")
                                   "hymba-1.5b", "internvl2-26b", "whisper-small"])
 @pytest.mark.parametrize("shape", [SMALL_TRAIN, SMALL_PREFILL, SMALL_DECODE])
 def test_lower_compile_smoke_mesh(arch, shape):
+    from repro.launch.compat import make_host_mesh
     from repro.launch.dryrun import build_lowerable
     from repro.launch.shardings import serve_rules, train_rules
 
     cfg = registry.get_smoke_config(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh((1, 1))
     rules = train_rules(mesh) if shape.kind == "train" else serve_rules(mesh)
     with jax.set_mesh(mesh), rules:
         fn, args, in_sh = build_lowerable(cfg, shape, mesh, rules)
@@ -198,3 +202,91 @@ def test_lower_compile_smoke_mesh(arch, shape):
     analysis = H.analyze_hlo_text(txt)
     assert analysis["flops"] > 0
     assert analysis["bytes"] > 0
+
+
+# ---------------------------------------------------------------------------
+# runtime set-up: compile cache, per-chip peaks, the merged-grid init
+# ---------------------------------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _cache_dir_after_one_compile(tmp_path, env_dir):
+    """Compile once in a fresh process with the cache helper on; return
+    the directory the helper reported (``env_dir`` None: the variable is
+    unset)."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compat import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "jax.jit(jnp.sin)(jnp.ones(3)).block_until_ready()\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=tmp_path, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_honours_env_dir(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: that directory receives the cache
+    and nothing is set in code."""
+    d = tmp_path / "cache"
+    assert _cache_dir_after_one_compile(tmp_path, d) == str(d)
+    assert os.listdir(d)
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(tmp_path):
+    """Unset: the cache goes to <repo>/.jax_cache, which git ignores."""
+    want = os.path.join(REPO, ".jax_cache")
+    assert _cache_dir_after_one_compile(tmp_path, None) == want
+    assert os.listdir(want)
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import chip_peaks
+
+    assert chip_peaks("TPU v5 lite").flops_bf16 == 197e12
+    assert chip_peaks("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")
+
+
+def test_kernel_profile_refuses_unknown_device_kind():
+    from repro.serving.obs import profile_kernel
+
+    with pytest.raises(KeyError, match="no published peaks"):
+        profile_kernel("fused_matmul", device_kind="cpu", repeats=1,
+                       m=1, t=8, d=8, f=8)
+
+
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "olmoe-1b-7b",
+                                  "xlstm-1.3b", "hymba-1.5b"])
+def test_init_instances_is_per_instance_init(arch):
+    """Instance i of the grid is init_instance(i), whatever M is, and the
+    grid holds it at the config's param dtype."""
+    from repro.models import common as C
+
+    cfg = registry.inference_config(registry.get_smoke_config(arch))
+    key = jax.random.PRNGKey(3)
+    grid3 = api.init_instances(cfg.with_(num_instances=3), key)
+    grid2 = api.init_instances(cfg.with_(num_instances=2), key)
+    master = cfg.with_(param_dtype="float32")
+    ax = api.axes(cfg.with_(num_instances=1))
+    for i in range(3):
+        want = api.init_instance(master, key, i)
+        got = C.take_instance(grid3, ax, i)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(g, np.float32),
+                np.asarray(w.astype(jnp.bfloat16), np.float32))
+    for a, b in zip(jax.tree.leaves(C.take_instance(grid2, ax, 1)),
+                    jax.tree.leaves(C.take_instance(grid3, ax, 1))):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

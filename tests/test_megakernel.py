@@ -287,6 +287,7 @@ def test_megakernel_streams_identical_on_mesh():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import numpy as np
         from repro import api
         from repro.configs import registry
@@ -316,7 +317,7 @@ def test_megakernel_streams_identical_on_mesh():
         ref = serve(None, 1, False)
         assert all(len(t) > 0 for t in ref), ref
         for shape in ((2, 4), (4, 2)):
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_host_mesh(shape)
             assert serve(mesh, 1, True) == ref, shape
             assert serve(mesh, 8, True) == ref, shape
         print("megakernel mesh streams OK")
@@ -342,6 +343,7 @@ def test_decode_attention_sharded_gqa_mqa():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import jax.numpy as jnp
         import numpy as np
         from repro.kernels.decode_attn import (
@@ -350,7 +352,7 @@ def test_decode_attention_sharded_gqa_mqa():
         from repro.launch.shardings import serve_rules
 
         assert len(jax.devices()) == 8
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         rules = serve_rules(mesh)
 
         cases = {(8, 4): "kv", (8, 1): "expand", (2, 1): None}

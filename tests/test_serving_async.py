@@ -125,6 +125,7 @@ def test_async_streams_identical_under_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import asyncio
         import jax
+        from repro.launch.compat import make_host_mesh
         import numpy as np
         from repro import api
         from repro.configs import registry
@@ -132,7 +133,7 @@ def test_async_streams_identical_under_mesh():
         from repro.serving import AsyncEngine, MultiModelServer, Request
 
         assert len(jax.devices()) == 8, jax.devices()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         M = 2
 
         def build(arch):

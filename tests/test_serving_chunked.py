@@ -12,6 +12,7 @@ for every family with at most two compiled prefill shapes.
 """
 from __future__ import annotations
 
+import functools
 import os
 import subprocess
 import sys
@@ -230,12 +231,15 @@ def test_padded_final_chunk_recurrent_carry_matches_exact(arch, ctx, total, pall
                 t[0, 0, j] = prompt[p - prefix]
         return jnp.asarray(t)
 
+    # jitted, as the serving runtime runs it: one compile per chunk width
+    # instead of an eager dispatch (and executable) per op per chunk
+    step = jax.jit(functools.partial(api.prefill_chunk, cfg))
     exact = api.init_chunk_carry(cfg, 1, 1, ctx)
     i = 0
     while i < total:
         c = min(chunk, total - i)
-        exact = api.prefill_chunk(cfg, params, {"tokens": toks_at(i, c)},
-                                  exact, jnp.full((1, 1), i, jnp.int32))
+        exact = step(params, {"tokens": toks_at(i, c)},
+                     exact, jnp.full((1, 1), i, jnp.int32))
         i += c
 
     padded = api.init_chunk_carry(cfg, 1, 1, ctx)
@@ -243,10 +247,8 @@ def test_padded_final_chunk_recurrent_carry_matches_exact(arch, ctx, total, pall
     while i < total:
         rem = min(chunk, total - i)
         valid = jnp.asarray((np.arange(chunk) < rem)[None, None])
-        padded = api.prefill_chunk(
-            cfg, params, {"tokens": toks_at(i, chunk), "valid": valid},
-            padded, jnp.full((1, 1), i, jnp.int32),
-        )
+        padded = step(params, {"tokens": toks_at(i, chunk), "valid": valid},
+                      padded, jnp.full((1, 1), i, jnp.int32))
         i += rem
 
     flat_e = jax.tree_util.tree_leaves_with_path(exact)
@@ -418,14 +420,14 @@ def test_moe_ep_shmap_masked_chainable_routing():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import jax.numpy as jnp
         import numpy as np
-        import repro  # installs compat shims
         from repro.configs import registry
         from repro.models import moe
         from repro.launch.shardings import serve_rules, moe_ep_shmap
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         # 8 experts on a 4-way model axis -> e_local = 2 per rank; low
         # capacity factor so the keep/drop rule actually fires
         cfg = registry.get_smoke_config("qwen3-moe-30b-a3b").with_(
@@ -485,10 +487,11 @@ def test_hybrid_and_moe_streams_identical_across_meshes():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import jax.numpy as jnp
         import numpy as np
         assert len(jax.devices()) == 8, jax.devices()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
 
         from repro import api
         from repro.configs import registry
@@ -524,7 +527,7 @@ def test_hybrid_and_moe_streams_identical_across_meshes():
             cfg, merged = build(arch)
             ref = serve(cfg, merged, None, ctx)
             assert all(len(t) > 0 for t in ref), (arch, ref)
-            one = serve(cfg, merged, jax.make_mesh((1, 1), ("data", "model")), ctx)
+            one = serve(cfg, merged, make_host_mesh((1, 1)), ctx)
             assert one == ref, (arch, one, ref)
             eight = serve(cfg, merged, mesh, ctx)
             assert eight == ref, (arch, eight, ref)

@@ -298,13 +298,14 @@ def test_multistep_streams_identical_on_mesh():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import numpy as np
         from repro import api
         from repro.configs import registry
         from repro.serving import MultiModelServer, Request
 
         assert len(jax.devices()) == 8, jax.devices()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
 
         M = 2
         cfg = registry.get_smoke_config("tinyllama-1.1b").with_(

@@ -191,6 +191,7 @@ def test_traced_streams_identical_under_mesh():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import numpy as np
         from repro import api
         from repro.configs import registry
@@ -198,7 +199,7 @@ def test_traced_streams_identical_under_mesh():
         from repro.serving import MultiModelServer, Request
 
         assert len(jax.devices()) == 8, jax.devices()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         M = 2
 
         def build(arch):
@@ -962,8 +963,11 @@ def test_http_slo_routes_and_health_integration():
 
 def test_profile_serving_kernels_smoke():
     cfg = registry.get_smoke_config("tinyllama-1.1b").with_(num_instances=2)
+    # the interpreter's timings against v5e peaks: this checks the
+    # record's arithmetic, not a device figure (records say interpret)
     rows = profile_serving_kernels(cfg, slots=2, max_context=32, chunk=8,
-                                   prefill_lanes=2, repeats=1)
+                                   prefill_lanes=2, repeats=1,
+                                   device_kind="TPU v5 lite")
     validate_profile(rows)
     assert [r["kernel"] for r in rows] == [
         "fused_matmul", "decode_attn", "chunk_prefill_attn",
@@ -986,5 +990,5 @@ def test_serving_shapes_handle_zero_dff_configs():
     assert shapes["mlstm_chunk"]["hd"] > 0
     assert shapes["slstm_cell"]["d"] > 0
     row = profile_kernel("fused_matmul", dtype=cfg.dtype, repeats=1,
-                         **shapes["fused_matmul"])
+                         device_kind="TPU v5 lite", **shapes["fused_matmul"])
     validate_profile([row])

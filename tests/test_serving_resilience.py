@@ -616,6 +616,7 @@ def test_supervised_crash_bit_identical_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import asyncio
         import jax
+        from repro.launch.compat import make_host_mesh
         import numpy as np
         from repro import api
         from repro.configs import registry
@@ -624,7 +625,7 @@ def test_supervised_crash_bit_identical_mesh():
                                    MultiModelServer, Request, Supervisor)
 
         assert len(jax.devices()) == 8, jax.devices()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         M = 2
         cfg1 = registry.get_smoke_config("tinyllama-1.1b").with_(
             num_instances=1, dtype="float32", param_dtype="float32")

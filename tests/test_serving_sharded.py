@@ -63,11 +63,12 @@ def _run_subprocess(body: str, *, header: str = ""):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
+        from repro.launch.compat import make_host_mesh
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         assert len(jax.devices()) == 8, jax.devices()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         """
     ) + header + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
@@ -90,7 +91,7 @@ def test_engine_streams_identical_across_meshes():
         cfg, merged = build("tinyllama-1.1b")
         ref, _ = serve(cfg, merged, None)
         assert all(len(t) > 0 for t in ref), ref
-        one, _ = serve(cfg, merged, jax.make_mesh((1, 1), ("data", "model")))
+        one, _ = serve(cfg, merged, make_host_mesh((1, 1)))
         assert one == ref, (one, ref)
         eight, _ = serve(cfg, merged, mesh)
         assert eight == ref, (eight, ref)
@@ -228,16 +229,18 @@ class _FakeMesh:
     size = 8
 
 
-def test_compat_polyfills_jax_set_mesh():
-    """Importing repro installs jax.set_mesh / jax.shard_map on JAX
-    versions that lack them (the test-suite and model zoo use the modern
-    spellings)."""
+def test_make_host_mesh_axes_are_auto():
+    """Every mesh is built with Auto axes: under jax.make_mesh's default
+    Explicit axes, ``constrain`` would assert layouts instead of
+    imposing them."""
     import jax
 
-    import repro  # noqa: F401  (import installs the shim)
+    from repro.launch.compat import make_host_mesh
 
-    assert callable(getattr(jax, "set_mesh"))
-    assert callable(getattr(jax, "shard_map"))
+    mesh = make_host_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert dict(mesh.shape) == {"data": jax.device_count(), "model": 1}
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
 
 
 def test_scheduler_data_shard_mapping():
