@@ -758,7 +758,7 @@ def main():
     cfg = base.with_(num_instances=m)
 
     t0 = time.perf_counter()
-    merged = api.init_instances(cfg, jax.random.PRNGKey(args.seed))
+    merged = api.init_instances(cfg, jax.random.PRNGKey(args.seed), mesh=mesh)
     jax.block_until_ready(merged)
     grid_init_ms = (time.perf_counter() - t0) * 1e3
 

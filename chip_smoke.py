@@ -15,12 +15,18 @@ Checks:
   and no prompt reaches the context cap, so nothing else may stop it);
 * NetFuse's claim, merged == per-instance: for one prompt per instance,
   the merged grid's prefill logits (bf16) match a plain f32 forward of
-  that instance alone, drawn independently from the same seed.
+  that instance alone.  The reference reuses the grid's own f32 draw of
+  the instance (``api.init_instance``), at highest matmul precision;
+* the serving path itself: the logits of each prompt's first generated
+  token, taken from a server (its chunked prefill, then the decode step
+  its decode block runs, under its mesh context), match the same
+  reference at the prompt's last position.
 
 Four chips (``--chips 4``, this phase only): M=16 instances (about 19.8
-GB of bf16 weights, more than one chip holds) served over a data=4 mesh
-of the four chips, against a one-chip M=4 run of instances 0-3 in the
-same process: prefill logits per instance must agree within the
+GB of bf16 weights, more than one chip holds), built in place over a
+data=4 mesh of the four chips and served there, against a one-chip M=4
+run of instances 0-3 in the same process: prefill logits and the
+serving path's first-token logits per instance must agree within the
 tolerance; greedy-token agreement is printed, not gated.
 
 Timings and memory printed on the way are informal readings, not
@@ -115,6 +121,51 @@ def serve_phase(cfg, grid, requests, *, slots: int, max_context: int,
     }
 
 
+def first_token_logits(cfg, grid, tokens, *, max_context: int, mesh=None):
+    """The serving path's f32 logits (n, V) of the first generated token
+    for prompt i = ``tokens[i]`` ((n, L) int32) sent to instance i.
+
+    A one-slot ``MultiModelServer`` prefills the n prompts together with
+    its chunked prefill (equal lengths, so they finish in the same call).
+    At its first decode dispatch the grid cache it holds is decoded once
+    with ``api.decode_step``, the function its decode block runs, under
+    its mesh context; then the dispatch goes on as usual."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro import api
+    from repro.serving import MultiModelServer, Request
+
+    server = MultiModelServer(cfg, grid, slots_per_instance=1,
+                              max_context=max_context, mesh=mesh)
+    decode = jax.jit(lambda p, c, t, q: api.decode_step(
+        cfg, p, c, t[..., None], q)[0].astype(jnp.float32))
+    step, got = server._step, []
+
+    def probe(params, cache, tok, pos, key, alive, remaining, k):
+        if not got:
+            require(np.asarray(alive)[:len(tokens), 0].all(),
+                    "the probe prompts did not finish prefill together")
+            # to the host whole: slicing a sharded array on the device
+            # would gather it onto one chip
+            got.append(np.asarray(decode(params, cache, tok, pos))[
+                :len(tokens), 0])
+        return step(params, cache, tok, pos, key, alive, remaining, k)
+
+    server._step = probe
+    for i, prompt in enumerate(np.asarray(tokens).tolist()):
+        server.submit(Request(instance=i, prompt=prompt, max_new_tokens=1))
+    for r in server.run_until_drained():
+        require(r.status == "ok", f"probe {r.request_id}: {r.status} "
+                f"({r.error})")
+    require(len(got) == 1, "the probe server never decoded")
+    # the server's reference cycles would keep its cache on the device
+    del server, step, probe
+    gc.collect()
+    return got[0]
+
+
 def merged_logits(cfg, grid, tokens, *, mesh=None):
     """f32 prefill logits (M, 1, L, V) of the merged grid for one prompt
     per instance (``tokens``: (M, 1, L) int32)."""
@@ -146,7 +197,7 @@ def reference_logits(cfg, key, i: int, tokens):
     from repro import api
 
     one = cfg.with_(num_instances=1, dtype="float32", param_dtype="float32")
-    # the grid's own f32 draw of instance i (api.init_instances)
+    # the grid's own f32 draw of instance i, as api.init_instances makes it
     params = api.init_instance(cfg.with_(param_dtype="float32"), key, i)
     with jax.default_matmul_precision("highest"):
         out = jax.jit(lambda p, t: api.train_logits(
@@ -163,9 +214,11 @@ def rel_err(a, b) -> float:
     return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
-def check_vs_reference(cfg, grid, key, *, prompt_len: int, seed: int) -> list:
-    """Per-instance relative error of merged logits against the f32
-    reference; raises ``CheckFailed`` beyond LOGITS_RTOL."""
+def check_vs_reference(cfg, grid, key, *, prompt_len: int, seed: int,
+                       max_context: int) -> None:
+    """Per-instance relative error against the f32 reference of the
+    merged prefill logits and of the serving path's first-token logits;
+    raises ``CheckFailed`` beyond LOGITS_RTOL."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -173,20 +226,24 @@ def check_vs_reference(cfg, grid, key, *, prompt_len: int, seed: int) -> list:
     rng = np.random.default_rng(seed)
     tokens = jnp.asarray(rng.integers(1, cfg.vocab_size,
                                       size=(m, 1, prompt_len)), jnp.int32)
-    got = merged_logits(cfg, grid, tokens)
+    got = np.asarray(merged_logits(cfg, grid, tokens))
+    served = first_token_logits(cfg, grid, tokens[:, 0],
+                                max_context=max_context)
     errs = []
     for i in range(m):
-        want = reference_logits(cfg, key, i, tokens[i:i + 1])
-        err = rel_err(got[i], want[0])
-        top1 = float(np.mean(np.argmax(np.asarray(got[i]), -1)
-                             == np.argmax(np.asarray(want[0]), -1)))
+        want = np.asarray(reference_logits(cfg, key, i, tokens[i:i + 1])[0])
+        err = rel_err(got[i], want)
+        top1 = float(np.mean(np.argmax(got[i], -1) == np.argmax(want, -1)))
         print(f"instance {i}: merged bf16 vs f32 reference logits rel err "
               f"{err:.3e} (tolerance {LOGITS_RTOL:g}), top-1 agreement "
               f"{top1:.3f}")
-        errs.append(err)
-        del want
+        last = want[0, -1]
+        err_served = rel_err(served[i], last)
+        print(f"instance {i}: served first-token logits vs f32 reference "
+              f"rel err {err_served:.3e} (tolerance {LOGITS_RTOL:g}), same "
+              f"top-1: {np.argmax(served[i]) == np.argmax(last)}")
+        errs += [err, err_served]
     require(max(errs) <= LOGITS_RTOL, f"logits rel errors {errs}")
-    return errs
 
 
 def one_chip(cfg, key, *, seed: int, slots: int, max_context: int,
@@ -201,7 +258,8 @@ def one_chip(cfg, key, *, seed: int, slots: int, max_context: int,
     jax.block_until_ready(grid)
     print(f"merged grid of {cfg.num_instances} instances built in "
           f"{time.perf_counter() - t0:.1f} s (compile included)")
-    check_vs_reference(cfg, grid, key, prompt_len=logits_len, seed=seed)
+    check_vs_reference(cfg, grid, key, prompt_len=logits_len, seed=seed,
+                       max_context=max_context)
     reqs = make_requests(cfg.num_instances, cfg.vocab_size,
                          per_instance=per_instance, lo=prompt_lo,
                          hi=prompt_hi, seed=seed)
@@ -227,7 +285,6 @@ def four_chips(cfg, key, devices, *, seed: int, slots: int, max_context: int,
 
     from repro import api
     from repro.launch.compat import make_host_mesh
-    from repro.launch.shardings import serve_rules, tree_shardings
 
     rng = np.random.default_rng(seed)
     tokens = jnp.asarray(rng.integers(1, cfg.vocab_size,
@@ -238,6 +295,8 @@ def four_chips(cfg, key, devices, *, seed: int, slots: int, max_context: int,
     small = cfg.with_(num_instances=m_small)
     grid = api.init_instances(small, key)
     want = np.asarray(merged_logits(small, grid, tokens[:m_small]))
+    want_served = first_token_logits(small, grid, tokens[:m_small, 0],
+                                     max_context=max_context)
     reqs_small = make_requests(m_small, cfg.vocab_size,
                                per_instance=per_instance, lo=prompt_lo,
                                hi=prompt_hi, seed=seed)
@@ -250,10 +309,8 @@ def four_chips(cfg, key, devices, *, seed: int, slots: int, max_context: int,
 
     big = cfg.with_(num_instances=m_mesh)
     mesh = make_host_mesh((len(devices), 1), devices=devices)
-    rules = serve_rules(mesh)
     t0 = time.perf_counter()
-    grid = api.init_instances(big, key, shardings=tree_shardings(
-        rules, api.axes(big), api.abstract_params(big)))
+    grid = api.init_instances(big, key, mesh=mesh)
     jax.block_until_ready(grid)
     share = grid["embed"].addressable_shards[0].data.shape[0]
     require(all(leaf.sharding.device_set == set(devices)
@@ -264,10 +321,17 @@ def four_chips(cfg, key, devices, *, seed: int, slots: int, max_context: int,
     # to the host shard by shard: slicing the sharded array on the device
     # would gather it onto one chip
     got = np.asarray(merged_logits(big, grid, tokens, mesh=mesh))[:m_small]
-    errs = [rel_err(got[i], want[i]) for i in range(m_small)]
-    for i, err in enumerate(errs):
+    got_served = first_token_logits(big, grid, tokens[:m_small, 0],
+                                    max_context=max_context, mesh=mesh)
+    errs = []
+    for i in range(m_small):
+        errs += [rel_err(got[i], want[i]),
+                 rel_err(got_served[i], want_served[i])]
         print(f"instance {i}: M={m_mesh} on the mesh vs M={m_small} on one "
-              f"chip, logits rel err {err:.3e} (tolerance {LOGITS_RTOL:g})")
+              f"chip, prefill logits rel err {errs[-2]:.3e}, served "
+              f"first-token logits rel err {errs[-1]:.3e} (tolerance "
+              f"{LOGITS_RTOL:g}), same first token: "
+              f"{np.argmax(got_served[i]) == np.argmax(want_served[i])}")
     require(max(errs) <= LOGITS_RTOL, f"logits rel errors {errs}")
     reqs_big = make_requests(m_mesh, cfg.vocab_size,
                              per_instance=per_instance, lo=prompt_lo,

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import audio, dense, hybrid, moe, ssm, vlm
+from repro.models import audio, common, dense, hybrid, moe, ssm, vlm
 from repro.models import layers as L
 
 _FAMILY = {
@@ -52,30 +52,23 @@ def _init_one(cfg, key, i):
     return init(cfg, jax.random.fold_in(key, i))
 
 
-def init_instances(cfg, key, *, shardings=None):
+def init_instances(cfg, key, *, mesh=None):
     """The merged grid of ``cfg.num_instances`` instances, instance i
     being :func:`init_instance` (drawn at f32, held at
-    ``cfg.param_dtype``).  Each instance is written into the grid as it
-    is drawn and then released, so no second full copy of the weights
-    ever exists.  ``shardings``: optional per-leaf shardings of the grid
-    (a mesh-sharded grid is then built in place)."""
-    ax = axes(cfg)
+    ``cfg.param_dtype``).  Each instance is drawn, merged into the grid
+    and released in turn (``common.merge_instances``), so no second full
+    copy of the weights ever exists.  With a ``mesh`` the grid is built
+    in place in the serving layout (``serve_rules``), never whole on one
+    device."""
+    shardings = None
+    if mesh is not None:
+        from repro.launch.shardings import serve_rules, tree_shardings
+        shardings = tree_shardings(serve_rules(mesh), axes(cfg),
+                                   abstract_params(cfg))
     master = cfg.with_(param_dtype="float32")
-    grid = jax.jit(lambda: jax.tree.map(
-        lambda a: jnp.zeros(a.shape, a.dtype), abstract_params(cfg)),
-        out_shardings=shardings)()
-
-    def put(grid, inst, i):
-        return jax.tree.map(
-            lambda a, g, w: jax.lax.dynamic_update_slice_in_dim(
-                g, w.astype(g.dtype), i, axis=a.index("instances")),
-            ax, grid, inst, is_leaf=lambda x: isinstance(x, tuple))
-
-    put = jax.jit(put, out_shardings=shardings, donate_argnums=(
-        (0,) if jax.default_backend() != "cpu" else ()))
-    for i in range(cfg.num_instances):
-        grid = put(grid, init_instance(master, key, i), i)
-    return grid
+    return common.merge_instances(
+        (init_instance(master, key, i) for i in range(cfg.num_instances)),
+        axes(cfg), like=abstract_params(cfg), shardings=shardings)
 
 
 def abstract_params(cfg):

@@ -262,17 +262,18 @@ def main():
     m = args.num_instances
     cfg = base.with_(num_instances=m)
 
-    # M independently-"fine-tuned" instances (different random weights),
-    # drawn straight into the merged grid (the paper's offline merge)
-    t0 = time.perf_counter()
-    merged = api.init_instances(cfg, jax.random.PRNGKey(args.seed))
-    jax.block_until_ready(merged)
-    print(f"NetFuse merged grid of {m} instances built in "
-          f"{(time.perf_counter()-t0)*1e3:.1f} ms")
-
     mesh = mesh_from_args(args.devices, args.mesh_shape)
     if mesh is not None:
         print(f"serving mesh: {dict(mesh.shape)} over {mesh.size} devices")
+
+    # M independently-"fine-tuned" instances (different random weights),
+    # drawn straight into the merged grid (the paper's offline merge),
+    # which on a mesh is built in place in its serving layout
+    t0 = time.perf_counter()
+    merged = api.init_instances(cfg, jax.random.PRNGKey(args.seed), mesh=mesh)
+    jax.block_until_ready(merged)
+    print(f"NetFuse merged grid of {m} instances built in "
+          f"{(time.perf_counter()-t0)*1e3:.1f} ms")
 
     faults = None
     if args.fault_plan:

@@ -297,12 +297,42 @@ def _inst_axis(ax: tuple) -> int:
 _is_axes_leaf = lambda x: isinstance(x, tuple)
 
 
-def merge_instances(params_list: list, axes_tree):
-    """NetFuse-merge M single-instance checkpoints -> one merged pytree."""
-    def _m(ax, *leaves):
-        i = _inst_axis(ax)
-        return jnp.concatenate(leaves, axis=i)
-    return jax.tree.map(_m, axes_tree, *params_list, is_leaf=_is_axes_leaf)
+def merge_instances(instances, axes_tree, *, like=None, shardings=None):
+    """NetFuse-merge M single-instance checkpoints -> one merged pytree.
+
+    Instance i is written into row i of a preallocated grid, in place, as
+    it arrives: ``instances`` may be a list or a lazy iterable, and an
+    iterable that draws each instance on demand never holds more than
+    one of them beside the grid.  ``like``: the grid's abstract tree
+    (shapes and dtypes; each instance is cast to it), needed for an
+    iterable; by default the instances' own, with M = their number.
+    ``shardings``: optional per-leaf shardings, so that a mesh-sharded
+    grid is built in place and never whole on one device."""
+    if like is None:
+        instances = list(instances)
+        m = len(instances)
+        like = jax.tree.map(
+            lambda ax, a: jax.ShapeDtypeStruct(
+                a.shape[:_inst_axis(ax)] + (m,) + a.shape[_inst_axis(ax) + 1:],
+                a.dtype),
+            axes_tree, instances[0], is_leaf=_is_axes_leaf)
+    grid = jax.jit(lambda: jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype), like),
+        out_shardings=shardings)()
+
+    def put(grid, inst, i):
+        return jax.tree.map(
+            lambda ax, g, w: jax.lax.dynamic_update_slice_in_dim(
+                g, w.astype(g.dtype), i, axis=_inst_axis(ax)),
+            axes_tree, grid, inst, is_leaf=_is_axes_leaf)
+
+    put = jax.jit(put, out_shardings=shardings, donate_argnums=0)
+    for i, inst in enumerate(instances):
+        grid = put(grid, inst, i)
+        # free the instance before the iterable draws the next one
+        del inst
+        jax.block_until_ready(grid)
+    return grid
 
 
 def split_instances(params, axes_tree):

@@ -50,6 +50,7 @@ def test_one_chip_phase_at_smoke_size(capsys):
                            jax.random.PRNGKey(0), **SMOKE)
     out = capsys.readouterr().out
     assert "all ok" in out and out.count("reference logits rel err") == 2
+    assert out.count("served first-token logits vs f32 reference") == 2
 
 
 def test_four_chip_phase_on_host_devices():
@@ -72,3 +73,4 @@ def test_four_chip_phase_on_host_devices():
                        text=True, env=env, cwd=REPO, timeout=600)
     assert r.returncode == 0, r.stderr[-4000:]
     assert "2 per chip" in r.stdout and "four-chip phase OK" in r.stdout
+    assert r.stdout.count("served first-token logits rel err") == 2

@@ -290,3 +290,60 @@ def test_init_instances_is_per_instance_init(arch):
                     jax.tree.leaves(C.take_instance(grid3, ax, 1))):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+
+
+def test_merge_instances_matches_concatenation():
+    """Checkpoints written row by row into the preallocated grid give the
+    concatenation of the instances, bit for bit and dtype for dtype."""
+    from repro.models import common as C
+
+    cfg1 = registry.get_smoke_config("qwen1.5-0.5b").with_(num_instances=1)
+    insts = [api.init(cfg1, jax.random.PRNGKey(i)) for i in range(3)]
+    ax = api.axes(cfg1)
+    got = C.merge_instances(insts, ax)
+    want = jax.tree.map(
+        lambda a, *leaves: jnp.concatenate(leaves, a.index("instances")),
+        ax, *insts, is_leaf=lambda x: isinstance(x, tuple))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_init_instances_on_a_mesh_is_built_in_place():
+    """With a mesh the grid is built in the engine's serving layout, each
+    of four devices holding its own quarter of the instances, and equals
+    the grid built on one device."""
+    code = (
+        "import os\n"
+        "os.environ['XLA_FLAGS'] = "
+        "'--xla_force_host_platform_device_count=4'\n"
+        "import jax, numpy as np\n"
+        "from repro import api\n"
+        "from repro.configs import registry\n"
+        "from repro.launch.compat import make_host_mesh\n"
+        "from repro.launch.shardings import serve_rules, tree_shardings\n"
+        "cfg = registry.inference_config(\n"
+        "    registry.get_smoke_config('qwen1.5-0.5b')).with_(num_instances=8)\n"
+        "key = jax.random.PRNGKey(0)\n"
+        "mesh = make_host_mesh((4, 1))\n"
+        "grid = api.init_instances(cfg, key, mesh=mesh)\n"
+        "ax = api.axes(cfg)\n"
+        "want = tree_shardings(serve_rules(mesh), ax, grid)\n"
+        "is_ax = lambda x: isinstance(x, tuple)\n"
+        "for a, g, s in zip(jax.tree.leaves(ax, is_leaf=is_ax),\n"
+        "                   jax.tree.leaves(grid), jax.tree.leaves(want)):\n"
+        "    assert g.sharding.is_equivalent_to(s, g.ndim), (g.sharding, s)\n"
+        "    for sh in g.addressable_shards:\n"
+        "        assert sh.data.shape[a.index('instances')] == 2, sh.data.shape\n"
+        "one = api.init_instances(cfg, key)\n"
+        "for g, w in zip(jax.tree.leaves(grid), jax.tree.leaves(one)):\n"
+        "    np.testing.assert_array_equal(np.asarray(g, np.float32),\n"
+        "                                  np.asarray(w, np.float32))\n"
+        "print('mesh grid OK')\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]
+    assert "mesh grid OK" in r.stdout

@@ -130,6 +130,28 @@ def test_logits_sample_matches_oracle_with_ties():
     assert got.dtype == jnp.int32
 
 
+def test_logits_sample_blocked_vocab_with_masked_tail():
+    """The prime vocab over 128-wide vocab blocks: three blocks, the last
+    padded past V and masked.  The greedy token is still jnp.argmax's,
+    with first-occurrence ties across block boundaries (7 / 100 / 255),
+    a tie between the second block and the tail (130 / 256), and a max
+    in the tail alone (256)."""
+    m, b, d, v = 2, 3, 32, 257
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    x = jax.random.normal(ks[0], (m, b, d))
+    scale = 1.0 + 0.1 * jax.random.normal(ks[1], (m, d))
+    head = jax.random.normal(ks[2], (m, d, v))
+    head = head.at[:, :, 100].set(head[:, :, 7])
+    head = head.at[:, :, 255].set(head[:, :, 7])
+    big = 50.0 * x[:, 0] * scale                   # (m, d): lane 0 wins
+    head = head.at[0, :, 130].set(big[0]).at[0, :, 256].set(big[0])
+    head = head.at[1, :, 256].set(big[1])
+    want = jax.jit(functools.partial(ref.logits_sample))(x, scale, head)
+    assert int(want[0, 0]) == 130 and int(want[1, 0]) == 256
+    got = ops.logits_sample(x, scale, head, block_v=128)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_tp_head_plan():
     """The shared head-grouping recipe (megakernel + decode_attn)."""
     assert tp_head_plan(8, 4, 1) is None      # no model axis
