@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no program ran on the
+device, from the profiler's trace, in %."""
+
+
+def read(ctx):
+    if ctx.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.busy_s / ctx.window_s)
