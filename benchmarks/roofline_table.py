@@ -2,20 +2,6 @@
 results/dryrun/*.json.
 
   PYTHONPATH=src python -m benchmarks.roofline_table [--mesh 1pod|2pod] [--tag ""]
-
-``--achieved`` switches to MEASURED mode: instead of rendering saved
-dry-run (predicted) rooflines, it times each serving Pallas kernel —
-fused_matmul, decode_attn, chunk_prefill_attn, mlstm_chunk, slstm_cell,
-plus the fused decode_layer megakernel and the logits_sample
-(final-norm + unembed + greedy argmax) kernel —
-at ``--arch``'s serving shapes and prints achieved FLOP/s / bytes/s
-against the same roofline envelope (repro.serving.obs.kernel_profile).
-On non-TPU backends the kernels run in the Pallas interpreter and every
-row says so — CPU figures characterize the interpreter, not silicon.
-
-  PYTHONPATH=src python -m benchmarks.roofline_table --achieved \\
-      [--arch tinyllama-1.1b] [--slots 4] [--achieved-context 128] \\
-      [--achieved-chunk 32] [--repeats 3] [--achieved-json out.json]
 """
 from __future__ import annotations
 
@@ -63,55 +49,13 @@ def fmt(rows, *, show_mem=True) -> str:
     return "\n".join(out)
 
 
-def achieved(args) -> None:
-    """The --achieved mode: time the serving kernels at --arch's shapes
-    and print the achieved-vs-roofline table."""
-    from repro.configs import registry
-    from repro.serving.obs import (
-        format_table, profile_serving_kernels, validate_profile,
-    )
-    cfg = (registry.get_config(args.arch) if args.full
-           else registry.get_smoke_config(args.arch))
-    cfg = cfg.with_(num_instances=args.num_instances)
-    rows = profile_serving_kernels(
-        cfg, slots=args.slots, max_context=args.achieved_context,
-        chunk=args.achieved_chunk, prefill_lanes=args.lanes,
-        repeats=args.repeats,
-    )
-    validate_profile(rows)
-    print(format_table(rows))
-    if rows and rows[0]["interpret"]:
-        print("\n(interpret mode: figures characterize the Pallas "
-              "interpreter on this backend, not silicon)")
-    if args.achieved_json:
-        with open(args.achieved_json, "w") as f:
-            json.dump(rows, f, indent=2)
-        print(f"wrote {args.achieved_json}")
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="1pod", choices=["1pod", "2pod"])
     ap.add_argument("--tag", default="")
     ap.add_argument("--dir", default="results/dryrun",
                     help="results/dryrun_baseline for the pre-§Perf snapshot")
-    ap.add_argument("--achieved", action="store_true",
-                    help="measure the serving kernels instead of rendering "
-                         "saved dry-run predictions")
-    ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--full", action="store_true",
-                    help="published config instead of the smoke config")
-    ap.add_argument("--num-instances", type=int, default=4)
-    ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--lanes", type=int, default=4)
-    ap.add_argument("--achieved-context", type=int, default=128)
-    ap.add_argument("--achieved-chunk", type=int, default=32)
-    ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--achieved-json", default=None)
     args = ap.parse_args()
-    if args.achieved:
-        achieved(args)
-        return
     rows = load(args.mesh, args.tag, args.dir)
     print(fmt(rows))
     n_ok = sum(1 for r in rows if r.get("ok"))

@@ -60,9 +60,7 @@ occupancy, idle-slot token-steps and the tracing on/off throughput A/B;
 ``dispatch_overhead_ms`` and ``mean_grid_occupancy`` are promoted to
 top-level fields so ``perf_delta.py --serve`` can diff the dispatch
 trajectory across PRs.  ``--trace-out trace.json`` dumps the pass's
-Chrome-trace JSON (Perfetto / chrome://tracing); ``--profile-kernels``
-times each serving Pallas kernel at the run's shapes and records
-achieved-vs-roofline figures (``kernel_roofline``).
+Chrome-trace JSON (Perfetto / chrome://tracing).
 
 Tenant accounting + SLOs (``tenant_attribution`` + ``load_gen.slo``
 sections, DESIGN.md §6.9): the traced pass also runs the per-tenant
@@ -655,9 +653,6 @@ def validate_record(record: dict) -> None:
                 f"{where}: megakernel path did not reduce launches "
                 f"({sides['megakernel']} vs {sides['unfused']})")
             assert sides["reduction"] > 1.0, where
-    if record.get("kernel_roofline") is not None:
-        from repro.serving.obs import validate_profile
-        validate_profile(record["kernel_roofline"])
     # recovery section (--fault-plan runs): the §6.8 acceptance
     # invariants are part of the record's validity — a recovery that
     # lost or duplicated tokens fails the bench, not just a test
@@ -728,10 +723,6 @@ def main():
     ap.add_argument("--trace-out", default=None, metavar="TRACE.json",
                     help="write the observability pass's Chrome-trace JSON "
                          "here (load in Perfetto / chrome://tracing)")
-    ap.add_argument("--profile-kernels", action="store_true",
-                    help="time each serving Pallas kernel at this config's "
-                         "shapes and record achieved-vs-roofline figures "
-                         "(record['kernel_roofline'])")
     ap.add_argument("--fault-plan", default=None, metavar="JSON",
                     help="run a fault-injected recovery pass (path or "
                          "inline JSON plan, DESIGN.md §6.8); the record "
@@ -863,15 +854,6 @@ def main():
     recovery = (_run_recovery(cfg, merged, mesh, args, reqs)
                 if args.fault_plan else None)
 
-    kernel_roofline = None
-    if args.profile_kernels:
-        from repro.serving.obs import profile_serving_kernels, format_table
-        kernel_roofline = profile_serving_kernels(
-            cfg, slots=args.slots, max_context=max_context,
-            chunk=args.chunk, prefill_lanes=args.lanes,
-        )
-        print(format_table(kernel_roofline))
-
     num_devices = fused_server.metrics.num_devices
     record = {
         "bench": "serve_fused_vs_sequential",
@@ -904,7 +886,6 @@ def main():
         # trajectory across PRs without digging into the section
         "dispatch_overhead_ms": obs["dispatch_overhead_ms"],
         "mean_grid_occupancy": obs["mean_grid_occupancy"],
-        "kernel_roofline": kernel_roofline,
         # only a measured figure when actually serving sharded
         "fused_tok_per_s_per_device": (
             fused["tok_per_s"] / num_devices if mesh is not None else None

@@ -82,7 +82,7 @@ from repro.models import common as C
 from repro.serving.metrics import ServerMetrics
 from repro.serving.obs.accounting import TenantAccounting
 from repro.serving.obs.flight import FlightRecorder
-from repro.serving.obs.trace import Tracer
+from repro.serving.obs.trace import NOSPAN, Tracer
 from repro.serving.prefill import ChunkedPrefill
 from repro.serving.resilience.faults import FaultInjector
 from repro.serving.resilience.health import HealthMonitor
@@ -436,6 +436,11 @@ class MultiModelServer:
         self.scheduler.submit(req)
         self.metrics.note_submit(req.instance)
         if self.tracer.enabled:
+            # enqueue: when the client handed the request over (the
+            # frontend's epoch); submit: when it reached the queue
+            self.tracer.request_event(req.request_id, "enqueue",
+                                      instance=req.instance,
+                                      t=req.submit_time)
             self.tracer.request_event(req.request_id, "submit",
                                       instance=req.instance)
         return req.request_id
@@ -603,12 +608,7 @@ class MultiModelServer:
         failures: list[Result] = []
         for req, out in completed:
             m, b = self._reserved[req.request_id]
-            trace_on = tr.enabled
-            # accounting shares the tracer's settle (timing-only: the
-            # scatter's result is consumed by this step's decode anyway,
-            # so numerics — and greedy streams — are untouched)
-            obs_on = trace_on or acct.enabled
-            if obs_on:
+            if tr.enabled or acct.enabled:
                 t0 = time.perf_counter()
             try:
                 if self.faults.armed:
@@ -627,25 +627,25 @@ class MultiModelServer:
                 continue
             self._reserved.pop(req.request_id)
             self.metrics.note_scatter()
-            if obs_on:
-                t1 = time.perf_counter()
-                # settle so the recorded device time is real execution,
-                # not dispatch
+            if tr.enabled:
+                # dispatch only: the scatter's device time is in the
+                # profiler's device trace, and settling here would
+                # serialise what an untraced step overlaps
+                tr.device_call(
+                    "scatter", t0, time.perf_counter(), step=self.steps,
+                    capacity=self.m * self.b,
+                    active=int((self.slot_busy
+                                & ~self.slot_prefilling).sum()),
+                )
+                tr.request_event(req.request_id, "prefill_done",
+                                 instance=m)
+            if acct.enabled:
+                # settle so the attributed time is real execution, not
+                # dispatch (timing-only: this step's decode consumes the
+                # scatter anyway, so numerics are untouched); a scatter
+                # admits exactly one request: whole wall to its tenant
                 jax.block_until_ready(self.cache)
-                t_settled = time.perf_counter()
-                if trace_on:
-                    tr.device_call(
-                        "scatter", t0, t1, t_settled,
-                        step=self.steps, capacity=self.m * self.b,
-                        active=int((self.slot_busy
-                                    & ~self.slot_prefilling).sum()),
-                    )
-                    tr.request_event(req.request_id, "prefill_done",
-                                     instance=m)
-                if acct.enabled:
-                    # a scatter admits exactly one request: whole wall
-                    # to its tenant
-                    acct.note_scatter(t_settled - t0, m)
+                acct.note_scatter(time.perf_counter() - t0, m)
             self.pos[m, b] = out.pos
             self.cur_tok[m, b] = out.last_token
             self.slot_prefilling[m, b] = False
@@ -694,74 +694,105 @@ class MultiModelServer:
         decode+sample block over the whole (M, B) grid, unroll its
         (k, M, B) tokens on the host, collect finished slots.
         Prefilling slots ride the grid as idle (masked) lanes, so long
-        prompts admit without stalling decode."""
-        out: list[Result] = self._pending_failures
-        self._pending_failures = []
-        if self.policy is not None:
-            out.extend(self._apply_policy())
-        self._admit()
-        if self.prefill.in_flight():
-            t0 = time.perf_counter()
-            try:
-                if self.faults.armed:
-                    self.faults.on_call("prefill")
-                completed = self.prefill.advance(
-                    self.params, self.chunk_budget, step=self.steps)
-            except Exception as exc:
-                out.extend(self._fail_prefilling(exc))
-                completed = []
-            stall = time.perf_counter() - t0
-            # decode-ready slots sat idle for this long while admission
-            # chunks ran — the quantity the chunk budget bounds
-            if (self.slot_busy & ~self.slot_prefilling).any():
-                self.metrics.note_admission_stall(stall)
-            out.extend(self._finish_prefills(completed))
-        decoding = self.slot_busy & ~self.slot_prefilling
-        if not decoding.any():
-            self.health.note_step()
-            return out
-        k = self._decode_horizon()
-        # per-slot decode budget for the on-device stop mask: a lane
-        # whose budget (or EOS / context) hits mid-block freezes there
-        remaining = np.zeros((self.m, self.b), np.int32)
-        for m in range(self.m):
-            for b in range(self.b):
-                if decoding[m, b]:
-                    req = self.active[m][b]
-                    remaining[m, b] = (
-                        req.max_new_tokens
-                        - len(self.generated[req.request_id])
-                    )
-        if self.mesh is not None:
-            # one host->device transfer each, straight to the grid sharding
-            def grid_put(x):
-                return jax.device_put(x, self._grid_shard)
-        else:
-            grid_put = jnp.asarray
-        tok_dev, pos_dev = grid_put(self.cur_tok), grid_put(self.pos)
-        alive_dev, rem_dev = grid_put(decoding), grid_put(remaining)
-        # fault hook BEFORE the dispatch: an injected raise/stall lands
-        # while host state is still consistent (no half-applied block),
-        # so a supervisor reset + requeue replays cleanly
-        poison = (
-            self.faults.on_call("decode") if self.faults.armed else ()
-        )
+        prompts admit without stalling decode.  With the tracer on, each
+        phase is a ``serve.*`` span inside ``serve.step`` (tagged with
+        the step number and the horizon k)."""
         tr = self.tracer
         trace_on = tr.enabled
-        t0 = time.perf_counter()
-        with self._ctx():
-            toks, emitted, oks, self.cache, self._key = self._step(
-                self.params, self.cache, tok_dev, pos_dev, self._key,
-                alive_dev, rem_dev, k,
+        with (tr.span("serve.step", step=self.steps) if trace_on
+              else NOSPAN) as span:
+            out: list[Result] = self._pending_failures
+            self._pending_failures = []
+            if self.policy is not None:
+                out.extend(self._apply_policy())
+            with tr.span("serve.admit") if trace_on else NOSPAN:
+                self._admit()
+            if self.prefill.in_flight():
+                t0 = time.perf_counter()
+                try:
+                    if self.faults.armed:
+                        self.faults.on_call("prefill")
+                    completed = self.prefill.advance(
+                        self.params, self.chunk_budget, step=self.steps)
+                except Exception as exc:
+                    out.extend(self._fail_prefilling(exc))
+                    completed = []
+                stall = time.perf_counter() - t0
+                # decode-ready slots sat idle for this long while
+                # admission chunks ran — the quantity the chunk budget
+                # bounds
+                if (self.slot_busy & ~self.slot_prefilling).any():
+                    self.metrics.note_admission_stall(stall)
+                with tr.span("serve.scatter") if trace_on else NOSPAN:
+                    out.extend(self._finish_prefills(completed))
+            decoding = self.slot_busy & ~self.slot_prefilling
+            if decoding.any():
+                out.extend(self._decode(decoding, span))
+            self.health.note_step()
+        return out
+
+    def _decode(self, decoding, span) -> list[Result]:
+        """One fused decode block over the slots in ``decoding`` and the
+        host unroll of its tokens; returns the requests it finished.
+        ``span`` is the step's trace span (None with the tracer off)."""
+        tr = self.tracer
+        trace_on = span is not None
+        with tr.span("serve.decode.prepare") if trace_on else NOSPAN:
+            k = self._decode_horizon()
+            # per-slot decode budget for the on-device stop mask: a lane
+            # whose budget (or EOS / context) hits mid-block freezes there
+            remaining = np.zeros((self.m, self.b), np.int32)
+            for m in range(self.m):
+                for b in range(self.b):
+                    if decoding[m, b]:
+                        req = self.active[m][b]
+                        remaining[m, b] = (
+                            req.max_new_tokens
+                            - len(self.generated[req.request_id])
+                        )
+            if self.mesh is not None:
+                # one host->device transfer each, straight to the grid
+                # sharding
+                def grid_put(x):
+                    return jax.device_put(x, self._grid_shard)
+            else:
+                grid_put = jnp.asarray
+            tok_dev, pos_dev = grid_put(self.cur_tok), grid_put(self.pos)
+            alive_dev, rem_dev = grid_put(decoding), grid_put(remaining)
+            # fault hook BEFORE the dispatch: an injected raise/stall
+            # lands while host state is still consistent (no half-applied
+            # block), so a supervisor reset + requeue replays cleanly
+            poison = (
+                self.faults.on_call("decode") if self.faults.armed else ()
             )
+        if trace_on:
+            span.set_metadata(k=k)
+        t0 = time.perf_counter()
+        with tr.span("serve.decode.dispatch") if trace_on else NOSPAN:
+            with self._ctx():
+                toks, emitted, oks, self.cache, self._key = self._step(
+                    self.params, self.cache, tok_dev, pos_dev, self._key,
+                    alive_dev, rem_dev, k,
+                )
         # jit return = host dispatch done (device still computing): the
         # per-call cost a K-step block amortizes K-fold
         t_dispatch = time.perf_counter()
         self.steps += 1
         # device_get blocks until the fused block's tokens land: the
         # settled timestamp is end-to-end device-call wall time
-        toks, emitted, oks = jax.device_get((toks, emitted, oks))
+        with tr.span("serve.decode.wait") if trace_on else NOSPAN:
+            toks, emitted, oks = jax.device_get((toks, emitted, oks))
         t_settled = time.perf_counter()
+        with tr.span("serve.decode.unroll") if trace_on else NOSPAN:
+            return self._unroll(k, decoding, toks, emitted, oks, poison,
+                                t0, t_dispatch, t_settled)
+
+    def _unroll(self, k, decoding, toks, emitted, oks, poison,
+                t0, t_dispatch, t_settled) -> list[Result]:
+        """Host unroll of a (k, M, B) token block: every per-token hook
+        (metrics, scheduler accounting, on_token streaming, finish
+        detection) fires per token, exactly as k separate one-token
+        steps would — only the dispatch count changed."""
         toks, emitted = np.asarray(toks), np.asarray(emitted)
         oks = np.array(oks)
         for i in poison:
@@ -774,6 +805,8 @@ class MultiModelServer:
         self.metrics.note_decode_call(steps=k, tokens=block_tokens,
                                       wall_s=t_settled - t0,
                                       dispatch_s=t_dispatch - t0)
+        tr = self.tracer
+        trace_on = tr.enabled
         if trace_on:
             tr.device_call(
                 "decode", t0, t_dispatch, t_settled,
@@ -798,10 +831,6 @@ class MultiModelServer:
             )
             replay_counts: dict[int, int] = {}
 
-        # host unroll of the (k, M, B) block: every per-token hook
-        # (metrics, scheduler accounting, on_token streaming, finish
-        # detection) fires per token, exactly as k separate one-token
-        # steps would — only the dispatch count changed
         done: list[Result] = []
         for j in range(k):
             for m in range(self.m):
@@ -840,11 +869,15 @@ class MultiModelServer:
                         if acct_on:
                             replay_counts[m] = replay_counts.get(m, 0) + 1
                     else:
+                        first = not gen and not req.emit_skip
                         self.metrics.note_token(
-                            m, first=not gen and not req.emit_skip,
+                            m, first=first,
                             submit_time=req.submit_time,
                             request_id=req.request_id,
                         )
+                        if first and trace_on:
+                            tr.request_event(req.request_id, "first_token",
+                                             instance=m)
                     self.scheduler.note_generated(m, 1)
                     gen.append(t)
                     self.pos[m, b] += 1
@@ -877,9 +910,7 @@ class MultiModelServer:
             # replay view (§6.8/§6.9): token-weighted share of this
             # call's wall spent regenerating already-delivered tokens
             acct.note_replay(replay_counts, t_settled - t0, block_tokens)
-        self.health.note_step()
-        out.extend(done)
-        return out
+        return done
 
     # -- overload brownout (DESIGN.md §6.8) -----------------------------------
 

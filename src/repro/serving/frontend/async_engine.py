@@ -58,6 +58,7 @@ import time
 from collections import deque
 
 from repro.serving.engine import MultiModelServer
+from repro.serving.obs.trace import NOSPAN
 from repro.serving.scheduler import Request, Result
 
 
@@ -489,10 +490,13 @@ class AsyncEngine:
 
     async def _drive(self) -> None:
         loop = self._loop
+        tracer = self.server.tracer
         try:
             while True:
-                self._apply_commands()
-                self._expire()
+                with (tracer.span("serve.frontend.commands")
+                      if tracer.enabled else NOSPAN):
+                    self._apply_commands()
+                    self._expire()
                 if not self.server.busy():
                     await self._notify_space()
                     if self._commands:
@@ -527,12 +531,14 @@ class AsyncEngine:
                     done = await self._step_future
                 finally:
                     self._step_started = None
-                for rid, tok in self._tok_buf:
-                    stream = self._streams.get(rid)
-                    if stream is not None:
-                        stream._push_token(tok)
-                for res in done:
-                    self._finish(res)
+                with (tracer.span("serve.frontend.deliver")
+                      if tracer.enabled else NOSPAN):
+                    for rid, tok in self._tok_buf:
+                        stream = self._streams.get(rid)
+                        if stream is not None:
+                            stream._push_token(tok)
+                    for res in done:
+                        self._finish(res)
                 await self._notify_space()
         except BaseException as e:
             if self.supervised:

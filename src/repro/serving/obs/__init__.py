@@ -1,14 +1,18 @@
 """Observability for the fused serving engine (DESIGN.md §6.5).
 
-``trace``          — ring-buffered step tracer: per-device-call events
-                     (wall + settled time, dispatch gap, grid occupancy,
-                     chunk validity) and request-lifecycle spans;
-                     Chrome-trace/Perfetto export + aggregate summaries.
+``trace``          — the step tracer: ``serve.*`` host spans inside each
+                     engine step, opened as ``jax.profiler``
+                     annotations so they land on the profiler's clock
+                     beside the device's programs, plus request stamps
+                     (enqueue, submit, admit, prefill_done, first_token,
+                     finish) and per-device-call events; Chrome-trace/
+                     Perfetto export + aggregate summaries.  ``POST
+                     /debug/trace/start`` turns it on, and a
+                     ``jax.profiler`` session running meanwhile shows the
+                     spans.
 ``prometheus``     — Prometheus text exposition of
                      ``ServerMetrics.snapshot()`` (Accept-negotiated on
                      ``GET /metrics``).
-``kernel_profile`` — achieved-vs-roofline timing of the serving Pallas
-                     kernels at serving shapes.
 ``slo``            — log-bucketed latency histograms (unbiased tail
                      percentiles) + per-instance TTFT/ITL/availability
                      objectives with error-budget burn rate (§6.9).
@@ -21,14 +25,6 @@
 """
 from repro.serving.obs.accounting import TenantAccounting
 from repro.serving.obs.flight import FlightRecorder
-from repro.serving.obs.kernel_profile import (
-    KERNELS,
-    format_table,
-    profile_kernel,
-    profile_serving_kernels,
-    serving_shapes,
-    validate_profile,
-)
 from repro.serving.obs.prometheus import render as render_prometheus
 from repro.serving.obs.slo import (
     LogHistogram,
@@ -42,7 +38,6 @@ from repro.serving.obs.trace import DeviceCallEvent, RequestEvent, Tracer
 __all__ = [
     "DeviceCallEvent",
     "FlightRecorder",
-    "KERNELS",
     "LogHistogram",
     "RequestEvent",
     "SLOConfig",
@@ -50,11 +45,6 @@ __all__ = [
     "Tracer",
     "evaluate_availability",
     "evaluate_objective",
-    "format_table",
-    "profile_kernel",
-    "profile_serving_kernels",
     "render_prometheus",
-    "serving_shapes",
-    "validate_profile",
     "worst_state",
 ]

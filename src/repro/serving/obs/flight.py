@@ -71,7 +71,7 @@ class FlightRecorder:
             if tracer is not None:
                 self._component(record, "trace_events", lambda: [
                     dict(dataclasses.asdict(ev), event=type(ev).__name__)
-                    for ev in tracer._snapshot()[-self.last_n:]])
+                    for ev in tracer.events()[-self.last_n:]])
             metrics = getattr(server, "metrics", None)
             if metrics is not None:
                 # embeds "slo" and "accounting" blocks when configured

@@ -1,35 +1,38 @@
-"""Step-level tracing for the fused serving engine.
+"""Step-level tracing for the fused serving engine (DESIGN.md §6.5).
 
-The engine's whole argument is GPU/TPU utilization — M merged instances
-sharing one fused (M, B) program should beat M sequential programs — yet
-until now the only figures were end-to-end tokens/s.  :class:`Tracer`
-makes the per-step anatomy visible: every device call (fused decode
-step, prefill chunk, slot scatter) becomes one ring-buffered event
-carrying
+:class:`Tracer` records two things while it is on:
 
-* **wall vs settled time** — dispatch wall (host time to issue the
-  async call) and settled wall (through ``block_until_ready`` /
-  ``device_get``), so host dispatch overhead separates from device
-  execution,
-* **dispatch gap** — host time since the previous device call settled:
-  the per-step overhead that makes the fused path lose to the
-  sequential baseline at small M (BENCH_serve.json ``speedup`` < 1),
-* **grid occupancy** — active decoding (M, B) slots vs capacity, the
-  paper's utilization claim made measurable per step, plus prefill
-  lanes busy and the validity fraction of padded chunks,
+* **step spans on the profiler's clock** — :meth:`Tracer.span` opens a
+  ``jax.profiler.TraceAnnotation`` (``serve.step``, ``serve.admit``,
+  ``serve.prefill``, ``serve.decode.wait``, ...), so a ``jax.profiler``
+  session shows where each engine step spends its host time on the same
+  clock as the device's programs, and a device-idle gap can be laid
+  against the host work that caused it;
+* **events in a ring buffer** — one per device call (fused decode
+  block, prefill chunk, slot scatter) with its dispatch time, and, for
+  decode blocks, the time their tokens reached the host and the **gap**
+  since the previous block's tokens did; plus grid occupancy, prefill
+  lanes busy and chunk validity.  Every request leaves stamps at the
+  program's boundaries (enqueue → submit → admit → prefill_done →
+  first_token → finish/cancel), correlated by request id, so its TTFT
+  splits into inbox, queued, prefill and first-block waits.
 
-and every request leaves a lifecycle trail (submit → admit →
-prefill-done → finish/cancel) correlated by request id, exported as
-spans.
+Turning it on adds no host synchronisation: a prefill chunk or scatter
+is recorded when it is dispatched, and only decode blocks, which the
+engine's own ``device_get`` settles in any case, carry a settled time.
+A traced run therefore runs the schedule an untraced one does; the
+device time of every call comes from the profiler's device trace.
 
-Off by default and **free when off**: every engine call site guards on
+Off by default and **free when off**: every call site guards on
 ``tracer.enabled`` before touching the tracer, so the disabled path
-constructs no event objects, takes no locks, and reads no clocks
-(tests assert zero event construction).  When on, events append to a
-bounded ``deque`` under a lock (the async frontend runs steps on an
-executor thread while ``GET /debug/trace`` exports from the event
-loop), so capture cost is O(1) per device call and memory is capped by
-``capacity``.
+constructs no event or span objects, takes no locks, and reads no
+clocks (tests assert that no tracer method runs).  When on, events
+append to a bounded ``deque`` under a lock (the async frontend runs
+steps on an executor thread while ``GET /debug/trace`` exports from the
+event loop), so capture cost is O(1) per call and memory is capped by
+``capacity``.  Stamps are ``time.perf_counter`` readings less
+:attr:`Tracer.epoch`, so a reader can put them back on the clock the
+clients use.
 
 Exports:
 
@@ -37,37 +40,55 @@ Exports:
   (``chrome://tracing`` or https://ui.perfetto.dev): device calls on a
   ``device`` process (one track per call kind), request phases on a
   ``requests`` process (one track per request id),
-* :meth:`Tracer.summary` — aggregates: dispatch-overhead p50/p95,
+* :meth:`Tracer.summary` — aggregates: decode dispatch-gap p50/p95,
   mean grid occupancy, idle-slot token-steps, prefill-lane occupancy,
   chunk validity.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_CAPACITY = 65536
 
-# request lifecycle stages, in order; consecutive pairs become spans
-STAGES = ("submit", "admit", "prefill_done", "finish")
+# request stamps, in lifecycle order; consecutive stamps a request has
+# become its phase spans (PHASES), a terminal stage also an instant
+STAGES = ("enqueue", "submit", "admit", "prefill_done", "first_token",
+          "finish", "cancel")
 TERMINAL = ("finish", "cancel")
+PHASES = {("enqueue", "submit"): "inbox",
+          ("submit", "admit"): "queued",
+          ("admit", "prefill_done"): "prefill",
+          ("prefill_done", "first_token"): "first_block",
+          ("first_token", "finish"): "decode",
+          # stamps a request lacks (capture started mid-request, a
+          # failure in an earlier phase) close with the next one it has
+          ("prefill_done", "finish"): "decode",
+          ("submit", "finish"): "request",
+          ("admit", "finish"): "serve"}
 # resilience stages (DESIGN.md §6.8): each occurrence renders as its
 # own instant (a request can requeue more than once, a driver can
 # restart more than once — these never collapse into lifecycle spans)
 RECOVERY = ("requeue", "restart", "shed", "quarantine")
 
+# what a call site enters when tracing is off: one shared no-op context
+NOSPAN = contextlib.nullcontext()
+
 
 @dataclasses.dataclass
 class DeviceCallEvent:
-    """One device call: a fused decode step, a prefill chunk/tail call,
-    or a prefill->grid slot scatter."""
+    """One device call: a fused decode block, a prefill chunk call, or a
+    prefill->grid slot scatter."""
     kind: str                  # "decode" | "prefill_chunk" | "scatter"
     t0: float                  # dispatch begin (tracer clock)
     t_dispatch: float          # dispatch returned (async call issued)
-    t_settled: float           # outputs settled on the host
-    gap_s: float               # host gap since the previous call settled
+    t_settled: float | None    # decode: its tokens reached the host
+    gap_s: float | None        # decode: host gap since the last block settled
     step: int                  # engine step counter at the call
     active: int = 0            # decoding (M, B) slots at the call
     capacity: int = 0          # M * B
@@ -82,20 +103,20 @@ class DeviceCallEvent:
 
 @dataclasses.dataclass
 class RequestEvent:
-    """One request-lifecycle edge, correlated by request id."""
+    """One request stamp (a STAGES or RECOVERY entry), by request id."""
     rid: int
-    stage: str                 # submit | admit | prefill_done | finish | cancel
-    t: float
+    stage: str
+    t: float                   # clock reading less the tracer's epoch
     instance: int = -1
     status: str | None = None  # terminal stages: ok/cancelled/expired/...
 
 
 class Tracer:
-    """Ring-buffered step tracer; disabled until :meth:`start`.
+    """Step spans and a ring buffer of events; disabled until :meth:`start`.
 
     Call sites MUST guard on ``tracer.enabled`` — the methods themselves
-    assume capture is on (that keeps the disabled hot path at literal
-    zero cost: one attribute read per guard)."""
+    assume capture is on (that keeps the disabled hot path at one
+    attribute read per guard)."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
                  clock=time.perf_counter):
@@ -123,6 +144,12 @@ class Tracer:
     def stop(self) -> None:
         self.enabled = False
 
+    @property
+    def epoch(self) -> float:
+        """The clock reading at :meth:`start`: an event's ``t`` (or
+        ``t0``) plus this is the raw ``clock()`` reading it was taken at."""
+        return self._epoch
+
     def __len__(self) -> int:
         return len(self._events)
 
@@ -134,32 +161,46 @@ class Tracer:
 
     # -- recording (call only when ``enabled``) ------------------------------
 
+    def span(self, name: str, **tags) -> TraceAnnotation:
+        """A host span on the profiler's clock, for a ``with`` block.
+        Keyword tags become the span's stats in the trace; more can be
+        added once known with ``set_metadata``."""
+        return TraceAnnotation(name, **tags)
+
     def device_call(self, kind: str, t0: float, t_dispatch: float,
-                    t_settled: float, *, step: int = 0, active: int = 0,
-                    capacity: int = 0, lanes_busy: int = 0, lanes: int = 0,
-                    valid_frac: float = 1.0, tokens: int = 0,
+                    t_settled: float | None = None, *, step: int = 0,
+                    active: int = 0, capacity: int = 0, lanes_busy: int = 0,
+                    lanes: int = 0, valid_frac: float = 1.0, tokens: int = 0,
                     pending: int = 0, decode_steps: int = 1) -> None:
         """Record one device call; timestamps are raw ``clock()`` reads
-        (the tracer rebases them onto its epoch)."""
-        last = self._last_settled
-        self._last_settled = t_settled
+        (the tracer rebases them onto its epoch).  ``t_settled`` is given
+        for decode blocks only, whose tokens the engine waits for."""
+        gap = settled = None
+        if t_settled is not None:
+            last = self._last_settled
+            self._last_settled = t_settled
+            gap = (t0 - last) if last is not None else 0.0
+            settled = t_settled - self._epoch
         self._append(DeviceCallEvent(
-            kind, t0 - self._epoch, t_dispatch - self._epoch,
-            t_settled - self._epoch,
-            gap_s=(t0 - last) if last is not None else 0.0,
-            step=step, active=active, capacity=capacity,
+            kind, t0 - self._epoch, t_dispatch - self._epoch, settled,
+            gap_s=gap, step=step, active=active, capacity=capacity,
             lanes_busy=lanes_busy, lanes=lanes, valid_frac=valid_frac,
             tokens=tokens, pending=pending, decode_steps=decode_steps,
         ))
 
     def request_event(self, rid: int, stage: str, *, instance: int = -1,
-                      status: str | None = None) -> None:
+                      status: str | None = None,
+                      t: float | None = None) -> None:
+        """Stamp ``stage`` for request ``rid`` now, or at ``t`` (a raw
+        ``clock()`` reading taken earlier, e.g. by the frontend)."""
         self._append(RequestEvent(
-            rid, stage, self.clock() - self._epoch, instance, status))
+            rid, stage, (self.clock() if t is None else t) - self._epoch,
+            instance, status))
 
     # -- export --------------------------------------------------------------
 
-    def _snapshot(self) -> list:
+    def events(self) -> list:
+        """The captured events, oldest first (a copy)."""
         with self._lock:
             return list(self._events)
 
@@ -168,10 +209,12 @@ class Tracer:
         format Perfetto and ``chrome://tracing`` load directly).
 
         Device calls render as complete ("X") slices on pid 0, one tid
-        per call kind, with the dispatch gap and occupancy in ``args``;
-        request lifecycles render on pid 1, one tid per request id, as
-        one slice per completed phase (queued / prefill / decode) plus
-        an instant ("i") event at terminal stages."""
+        per call kind, from dispatch to settle for decode blocks and over
+        the dispatch for the others, with occupancy in ``args``; request
+        stamps render on pid 1, one tid per request id, as one slice per
+        phase between consecutive stamps (inbox / queued / prefill /
+        first_block / decode) plus an instant ("i") event at terminal
+        stages."""
         us = lambda t: t * 1e6
         kinds: dict[str, int] = {}
         events: list[dict] = [
@@ -181,29 +224,32 @@ class Tracer:
              "args": {"name": "requests"}},
         ]
         marks: dict[int, dict[str, RequestEvent]] = {}
-        for ev in self._snapshot():
+        for ev in self.events():
             if isinstance(ev, DeviceCallEvent):
                 tid = kinds.setdefault(ev.kind, len(kinds))
+                end = ev.t_dispatch if ev.t_settled is None else ev.t_settled
+                args = {
+                    "step": ev.step,
+                    "dispatch_ms": 1e3 * (ev.t_dispatch - ev.t0),
+                    "active_slots": ev.active,
+                    "slot_capacity": ev.capacity,
+                    "occupancy": (ev.active / ev.capacity
+                                  if ev.capacity else 0.0),
+                    "lanes_busy": ev.lanes_busy,
+                    "lanes": ev.lanes,
+                    "valid_frac": ev.valid_frac,
+                    "tokens": ev.tokens,
+                    "pending": ev.pending,
+                    "decode_steps": ev.decode_steps,
+                }
+                if ev.t_settled is not None:
+                    args["settled_ms"] = 1e3 * (ev.t_settled - ev.t0)
+                    args["gap_ms"] = 1e3 * ev.gap_s
                 events.append({
                     "name": ev.kind, "ph": "X", "cat": "device",
                     "pid": 0, "tid": tid,
-                    "ts": us(ev.t0), "dur": max(us(ev.t_settled - ev.t0), 0.0),
-                    "args": {
-                        "step": ev.step,
-                        "dispatch_ms": 1e3 * (ev.t_dispatch - ev.t0),
-                        "settled_ms": 1e3 * (ev.t_settled - ev.t0),
-                        "gap_ms": 1e3 * ev.gap_s,
-                        "active_slots": ev.active,
-                        "slot_capacity": ev.capacity,
-                        "occupancy": (ev.active / ev.capacity
-                                      if ev.capacity else 0.0),
-                        "lanes_busy": ev.lanes_busy,
-                        "lanes": ev.lanes,
-                        "valid_frac": ev.valid_frac,
-                        "tokens": ev.tokens,
-                        "pending": ev.pending,
-                        "decode_steps": ev.decode_steps,
-                    },
+                    "ts": us(ev.t0), "dur": max(us(end - ev.t0), 0.0),
+                    "args": args,
                 })
             elif ev.stage in RECOVERY:
                 # rendered immediately (not via marks): every
@@ -222,24 +268,13 @@ class Tracer:
         for tid, kind in sorted((v, k) for k, v in kinds.items()):
             events.append({"name": "thread_name", "ph": "M", "pid": 0,
                            "tid": tid, "args": {"name": kind}})
-        span_names = {("submit", "admit"): "queued",
-                      ("admit", "prefill_done"): "prefill",
-                      ("prefill_done", "finish"): "decode",
-                      # zero-work admissions skip prefill_done; cancels
-                      # can land in any phase — close with what exists
-                      ("submit", "finish"): "request",
-                      ("submit", "cancel"): "cancelled",
-                      ("admit", "finish"): "serve",
-                      ("admit", "cancel"): "cancelled",
-                      ("prefill_done", "cancel"): "cancelled"}
         for rid, stages in marks.items():
-            order = [s for s in
-                     ("submit", "admit", "prefill_done", "finish", "cancel")
-                     if s in stages]
+            order = [s for s in STAGES if s in stages]
             for a, b in zip(order, order[1:]):
                 ea, eb = stages[a], stages[b]
                 events.append({
-                    "name": span_names.get((a, b), f"{a}->{b}"),
+                    "name": ("cancelled" if b == "cancel"
+                             else PHASES.get((a, b), f"{a}->{b}")),
                     "ph": "X", "cat": "request", "pid": 1, "tid": rid,
                     "ts": us(ea.t), "dur": max(us(eb.t - ea.t), 0.0),
                     "args": {"request_id": rid, "instance": eb.instance
@@ -264,16 +299,15 @@ class Tracer:
         # module-level import here would close an import cycle through
         # the obs package __init__
         from repro.serving.metrics import percentiles
-        calls = [e for e in self._snapshot()
+        calls = [e for e in self.events()
                  if isinstance(e, DeviceCallEvent)]
         decodes = [e for e in calls if e.kind == "decode"]
         chunks = [e for e in calls if e.kind == "prefill_chunk"]
-        # the first call of a capture has no predecessor: gap 0 by
-        # construction, harmless in the percentiles
-        gaps = [e.gap_s for e in calls]
+        # host time between decode blocks (the first of a capture has no
+        # predecessor: gap 0 by construction, harmless in the percentiles)
+        gaps = [e.gap_s for e in decodes]
         occ = [e.active / e.capacity for e in decodes if e.capacity]
         decode_tokens = sum(e.tokens for e in decodes)
-        decode_gap_s = sum(e.gap_s for e in decodes)
         out = {
             "device_calls": len(calls),
             "decode_steps": len(decodes),   # decode device calls (blocks)
@@ -286,17 +320,17 @@ class Tracer:
                 sum(e.decode_steps for e in decodes) / len(decodes)
                 if decodes else 0.0),
             "dispatch_overhead_per_token_ms": (
-                1e3 * decode_gap_s / decode_tokens
+                1e3 * sum(gaps) / decode_tokens
                 if decode_tokens else None),
             "prefill_chunks": len(chunks),
             "scatters": sum(1 for e in calls if e.kind == "scatter"),
-            # host time between device calls — the per-step dispatch
-            # overhead the megakernel/multi-step-decode work must attack
+            # host time between decode blocks — the per-step overhead the
+            # megakernel/multi-step-decode work must attack
             "dispatch_overhead_ms": percentiles(gaps),
             "mean_dispatch_gap_ms": (
                 1e3 * sum(gaps) / len(gaps) if gaps else 0.0),
             "settled_ms": percentiles(
-                [e.t_settled - e.t0 for e in calls]),
+                [e.t_settled - e.t0 for e in decodes]),
             # the utilization claim: decoding slots / grid capacity
             "mean_grid_occupancy": sum(occ) / len(occ) if occ else 0.0,
             # slot-steps the fused program computed for nobody (an idle

@@ -49,6 +49,7 @@ from repro import api
 from repro.launch.compat import mesh_context
 from repro.models import common as C
 from repro.models.common import constrain_tree, gather_instances
+from repro.serving.obs.trace import NOSPAN
 from repro.serving.scheduler import Request
 
 KV_FAMILIES = ("dense", "moe", "vlm", "audio")
@@ -268,6 +269,13 @@ class ChunkedPrefill:
         carry, which the NEXT advance updates in place — consume (scatter)
         them before advancing again, as the engine does.  ``step`` tags
         trace events with the engine's step counter."""
+        tr = self.tracer
+        trace_on = tr is not None and tr.enabled
+        with tr.span("serve.prefill") if trace_on else NOSPAN:
+            return self._advance(params, budget, step, trace_on)
+
+    def _advance(self, params, budget: int, step: int,
+                 trace_on: bool) -> list[tuple[Request, PrefillOut]]:
         done: list[tuple[Request, PrefillOut]] = []
         # zero-work lanes (single-token prompts of prefix-less families)
         # complete immediately from the pristine init carry — their grid
@@ -296,8 +304,7 @@ class ChunkedPrefill:
                                 if self._lanes[i].total > self._lanes[i].next_pos]
                     if not workable:
                         break
-                    self._step(params, workable, self.chunk, fold=True,
-                               step=step)
+                    c, fold = self.chunk, True
                 else:
                     chunkable = [i for i in busy
                                  if self._lanes[i].total - self._lanes[i].next_pos >= self.chunk]
@@ -312,8 +319,12 @@ class ChunkedPrefill:
                     run_tail = bool(tailable) and (self._tail_turn or not chunkable)
                     self._tail_turn = not run_tail
                     workable = tailable if run_tail else chunkable
-                    c = 1 if run_tail else self.chunk
-                    self._step(params, workable, c, step=step)
+                    c, fold = (1 if run_tail else self.chunk), False
+                with (self.tracer.span("serve.prefill.chunk",
+                                       lanes=len(workable))
+                      if trace_on else NOSPAN) as span:
+                    self._step(params, workable, c, fold=fold, step=step,
+                               span=span)
                 stepped = True
                 budget -= 1
                 for i in busy:
@@ -328,7 +339,8 @@ class ChunkedPrefill:
             # settle the async dispatch so the engine's admission-stall
             # timer measures device execution, not just dispatch (the
             # scatter/decode it times against depend on this carry anyway)
-            jax.block_until_ready(self._carry)
+            with self.tracer.span("serve.prefill.wait") if trace_on else NOSPAN:
+                jax.block_until_ready(self._carry)
             if self.metrics is not None:
                 self.metrics.note_prefill_wall(time.perf_counter() - t0)
         for _, out in done:
@@ -336,7 +348,10 @@ class ChunkedPrefill:
         return zero_done + done
 
     def _step(self, params, workable: list[int], c: int, fold: bool = False,
-              step: int = 0) -> None:
+              step: int = 0, span=None) -> None:
+        """One chunk device call over the ``workable`` lanes.  ``span`` is
+        the call's trace span (None with the tracer off), tagged here with
+        the real tokens it advances."""
         k = self.lanes
         toks = np.zeros((k, 1, c), np.int32)
         inst = np.zeros((k,), np.int32)
@@ -378,12 +393,12 @@ class ChunkedPrefill:
                 if lane.req is not None and lane.total > 0:
                     limit[i, 0] = moe.capacity(self.cfg, lane.total)
             extras["moe_limit"] = jnp.asarray(limit)
-        tr = self.tracer
-        trace_on = tr is not None and tr.enabled
+        trace_on = span is not None
+        if trace_on:
+            span.set_metadata(tokens=tokens_done)
         acct = self.accounting
         acct_on = acct is not None and acct.enabled
-        obs_on = trace_on or acct_on
-        if obs_on:
+        if trace_on or acct_on:
             t0 = time.perf_counter()
         self._carry = self._fn(c)(
             params, jnp.asarray(inst), jnp.asarray(toks), self._carry,
@@ -398,26 +413,24 @@ class ChunkedPrefill:
         for lane in self._lanes:
             if lane.req is not None:
                 lane.fresh = False
-        if obs_on:
-            t_dispatch = time.perf_counter()
-            # settling per chunk is a tracing/accounting-ON cost: it buys
-            # the true per-call device time; the unobserved path keeps
-            # its async dispatch (one settle per advance)
+        if trace_on:
+            # dispatch only: the chunk's device time is in the profiler's
+            # device trace; the one settle is at the end of ``advance``
+            self.tracer.device_call(
+                "prefill_chunk", t0, time.perf_counter(),
+                step=step, lanes_busy=self.in_flight(), lanes=self.lanes,
+                valid_frac=tokens_done / (len(workable) * c) if workable else 1.0,
+                tokens=tokens_done,
+            )
+        if acct_on:
+            # settling per chunk is an accounting-ON cost: it buys the
+            # true per-call device time.  Lane-weighted attribution: each
+            # busy lane charges its tenant wall/lanes; unoccupied lanes
+            # are shared idle
             jax.block_until_ready(self._carry)
-            t_settled = time.perf_counter()
-            if trace_on:
-                tr.device_call(
-                    "prefill_chunk", t0, t_dispatch, t_settled,
-                    step=step, lanes_busy=self.in_flight(), lanes=self.lanes,
-                    valid_frac=tokens_done / (len(workable) * c) if workable else 1.0,
-                    tokens=tokens_done,
-                )
-            if acct_on:
-                # lane-weighted attribution: each busy lane charges its
-                # tenant wall/lanes; unoccupied lanes are shared idle
-                acct.note_prefill(
-                    t_settled - t0,
-                    [int(inst[i]) for i in workable], self.lanes)
+            acct.note_prefill(
+                time.perf_counter() - t0,
+                [int(inst[i]) for i in workable], self.lanes)
         if self.metrics is not None:
             self.metrics.note_prefill_batch(len(workable), tokens_done)
 

@@ -257,14 +257,6 @@ def test_chip_peaks_keyed_by_device_kind():
         chip_peaks("cpu")
 
 
-def test_kernel_profile_refuses_unknown_device_kind():
-    from repro.serving.obs import profile_kernel
-
-    with pytest.raises(KeyError, match="no published peaks"):
-        profile_kernel("fused_matmul", device_kind="cpu", repeats=1,
-                       m=1, t=8, d=8, f=8)
-
-
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "olmoe-1b-7b",
                                   "xlstm-1.3b", "hymba-1.5b"])
 def test_init_instances_is_per_instance_init(arch):

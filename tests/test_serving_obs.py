@@ -1,18 +1,23 @@
 """Observability tests (ISSUE 6): step tracing, Prometheus exposition,
-kernel profiling, and the HTTP debug surface.
+and the HTTP debug surface.
 
 The load-bearing contracts:
 
 * tracing OFF is free — call sites guard on ``tracer.enabled``, so the
-  disabled path runs NO tracer code at all (no event construction, no
-  locks, no clock reads inside the tracer) — asserted by making every
-  tracer method explode and draining a full workload,
+  disabled path runs NO tracer code at all (no event or span
+  construction, no locks, no clock reads inside the tracer) — asserted
+  by making every tracer method explode and draining a full workload,
 * tracing ON is invisible to results — traced greedy streams are
   bit-identical to untraced ones (dense + recurrent, no-mesh and an
-  8-device mesh subprocess),
+  8-device mesh subprocess) — and to the schedule: a traced drain makes
+  exactly the host synchronisations an untraced one does,
+* a traced step opens the ``serve.*`` spans, in order and nested, and
+  every request's stamps run enqueue <= submit <= admit <= prefill_done
+  <= first_token <= finish,
 * ``export_chrome()`` emits loadable Chrome-trace JSON: ``X`` slices
-  for device calls with dispatch/gap/occupancy args, request-lifecycle
-  spans correlated by request id, ``i`` instants at terminal stages,
+  for device calls with dispatch/occupancy args (settle and gap on
+  decode blocks), request phase spans correlated by request id, ``i``
+  instants at terminal stages,
 * the Prometheus rendering parses line-by-line (format 0.0.4) and its
   label escaping round-trips,
 * ``ServerMetrics.snapshot()`` carries the cumulative device-call and
@@ -45,6 +50,7 @@ import re
 import subprocess
 import sys
 import textwrap
+import threading
 
 import pytest
 
@@ -62,16 +68,14 @@ from repro.serving import (
 )
 from repro.serving.obs import (
     LogHistogram,
+    RequestEvent,
     Tracer,
     evaluate_availability,
     evaluate_objective,
-    profile_kernel,
-    profile_serving_kernels,
     render_prometheus,
-    serving_shapes,
-    validate_profile,
     worst_state,
 )
+from repro.serving.obs import trace as trace_mod
 from repro.serving.obs.prometheus import escape_label
 from repro.serving.obs.slo import HIST_GROWTH
 
@@ -108,8 +112,9 @@ def _reqs():
 
 def test_tracing_off_runs_no_tracer_code(monkeypatch):
     """With capture off, a full drain (submit, admit, prefill, scatter,
-    decode, finish, cancel) must never enter the tracer: every recording
-    method is replaced with a bomb."""
+    decode, finish, cancel), synchronous and through the async frontend,
+    must never enter the tracer: every recording method and the span
+    helper are replaced with a bomb."""
     cfg, params = _build("tinyllama-1.1b")
     server = _server(cfg, params)
 
@@ -118,6 +123,7 @@ def test_tracing_off_runs_no_tracer_code(monkeypatch):
 
     monkeypatch.setattr(server.tracer, "device_call", boom)
     monkeypatch.setattr(server.tracer, "request_event", boom)
+    monkeypatch.setattr(server.tracer, "span", boom)
     monkeypatch.setattr(server.tracer, "_append", boom)
     ids = [server.submit(r) for r in _reqs()]
     # exercise the cancel call sites too (queued cancel)
@@ -126,6 +132,13 @@ def test_tracing_off_runs_no_tracer_code(monkeypatch):
     results = server.run_until_drained()
     assert {r.request_id for r in results} == set(ids)
     assert all(r.status == "ok" for r in results)
+
+    async def run():
+        async with AsyncEngine(server) as engine:
+            streams = [await engine.submit(r) for r in _reqs()]
+            return [await s.result() for s in streams]
+
+    assert all(r.status == "ok" for r in asyncio.run(run()))
     assert len(server.tracer) == 0
 
 
@@ -280,25 +293,34 @@ def test_export_chrome_schema_and_json_roundtrip():
     for e in device:
         assert e["ts"] >= 0 and e["dur"] >= 0
         args = e["args"]
-        for k in ("step", "dispatch_ms", "settled_ms", "gap_ms",
-                  "active_slots", "slot_capacity", "occupancy"):
+        for k in ("step", "dispatch_ms", "active_slots", "slot_capacity",
+                  "occupancy"):
             assert k in args, (e["name"], k)
         assert 0.0 <= args["occupancy"] <= 1.0
+        # only decode blocks settle (at the engine's own device_get):
+        # chunks and scatters are recorded at dispatch
+        settles = e["name"] == "decode"
+        assert ("settled_ms" in args) == settles, e
+        assert ("gap_ms" in args) == settles, e
+        if not settles:
+            assert e["dur"] == pytest.approx(1e3 * args["dispatch_ms"])
     decode_args = [e["args"] for e in device if e["name"] == "decode"]
     assert any(a["active_slots"] > 0 for a in decode_args)
     assert all(a["slot_capacity"] == server.m * server.b
                for a in decode_args)
 
     # every request leaves spans on its own track, ending in a terminal
-    # instant; the full lifecycle (multi-chunk prompt) names all three
+    # instant; every one of them ran the whole lifecycle, so each names
+    # all five phases, in order
     rids = {e["tid"] for e in spans}
     assert len(rids) == len(_reqs())
     assert {e["name"] for e in instants} == {"finish:ok"}
     by_rid = {}
     for e in spans:
         by_rid.setdefault(e["tid"], []).append(e["name"])
-    assert any(set(v) == {"queued", "prefill", "decode"}
-               for v in by_rid.values()), by_rid
+    for v in by_rid.values():
+        assert v == ["inbox", "queued", "prefill", "first_block",
+                     "decode"], by_rid
     # process/thread naming metadata for the two trace processes
     assert {(e["name"], e.get("pid")) for e in meta} >= {
         ("process_name", 0), ("process_name", 1), ("thread_name", 0)}
@@ -320,22 +342,28 @@ def test_summary_aggregates_from_synthetic_events():
     tr = Tracer(clock=lambda: 0.0)
     tr.start()                                    # epoch = 0.0
     tr.device_call("decode", 1.00, 1.01, 1.05, step=0, active=2, capacity=4)
-    tr.device_call("decode", 1.10, 1.11, 1.15, step=1, active=4, capacity=4)
-    tr.device_call("prefill_chunk", 1.20, 1.21, 1.25, step=2,
+    tr.device_call("prefill_chunk", 1.06, 1.07, step=1,
                    lanes_busy=1, lanes=4, valid_frac=0.5, tokens=8)
-    tr.device_call("scatter", 1.30, 1.31, 1.35, step=2)
+    tr.device_call("scatter", 1.08, 1.09, step=1)
+    tr.device_call("decode", 1.10, 1.11, 1.16, step=1, active=4, capacity=4,
+                   tokens=4)
     s = tr.summary()
     assert s["device_calls"] == 4
     assert s["decode_steps"] == 2
     assert s["prefill_chunks"] == 1
     assert s["scatters"] == 1
-    # gaps: 0 (first), 1.10-1.05, 1.20-1.15, 1.30-1.25 -> 0/50/50/50 ms
+    # gaps and settle over decode blocks only: 0 (first), 1.10 - 1.05
     assert s["dispatch_overhead_ms"]["p95"] == pytest.approx(50.0)
-    assert s["mean_dispatch_gap_ms"] == pytest.approx(37.5)
+    assert s["mean_dispatch_gap_ms"] == pytest.approx(25.0)
+    assert s["dispatch_overhead_per_token_ms"] == pytest.approx(12.5)
+    assert s["settled_ms"]["p50"] == pytest.approx(50.0)
+    assert s["settled_ms"]["p99"] == pytest.approx(60.0)
     assert s["mean_grid_occupancy"] == pytest.approx(0.75)
     assert s["idle_slot_token_steps"] == 2
     assert s["mean_prefill_lane_occupancy"] == pytest.approx(0.25)
     assert s["mean_chunk_validity"] == pytest.approx(0.5)
+    chunk = next(e for e in tr.events() if e.kind == "prefill_chunk")
+    assert chunk.t_settled is None and chunk.gap_s is None
 
 
 def test_request_spans_from_synthetic_lifecycle():
@@ -353,6 +381,211 @@ def test_request_spans_from_synthetic_lifecycle():
     assert spans["queued"]["dur"] == pytest.approx(1e6)
     assert spans["decode"]["dur"] == pytest.approx(1e6)
     assert [e["name"] for e in ev if e["ph"] == "i"] == ["finish:ok"]
+
+
+def test_request_phases_from_synthetic_stamps():
+    """The frontend's enqueue stamp is taken before the tracer sees the
+    request (``t=``), and a reader puts stamps back on the raw clock
+    with ``epoch``; consecutive stamps become the five phase spans."""
+    times = iter([10.0, 12.0, 13.0, 14.5, 15.0, 17.0])
+    tr = Tracer(clock=lambda: next(times))
+    tr.start()                                    # epoch = 10.0
+    assert tr.epoch == 10.0
+    tr.request_event(3, "enqueue", instance=0, t=11.0)
+    for stage in ("submit", "admit", "prefill_done", "first_token"):
+        tr.request_event(3, stage, instance=0)
+    tr.request_event(3, "finish", instance=0, status="ok")
+    stamps = {e.stage: e.t + tr.epoch for e in tr.events()}
+    assert stamps == {"enqueue": 11.0, "submit": 12.0, "admit": 13.0,
+                      "prefill_done": 14.5, "first_token": 15.0,
+                      "finish": 17.0}
+    ev = tr.export_chrome()["traceEvents"]
+    spans = [(e["name"], e["ts"], e["dur"]) for e in ev if e["ph"] == "X"]
+    assert spans == [("inbox", 1e6, 1e6), ("queued", 2e6, 1e6),
+                     ("prefill", 3e6, 1.5e6), ("first_block", 4.5e6, 0.5e6),
+                     ("decode", 5e6, 2e6)]
+
+
+# ---------------------------------------------------------------------------
+# tracing on: serve.* spans, request stamps, no extra host synchronisation
+# ---------------------------------------------------------------------------
+
+# each engine span and the span it is opened inside
+SPAN_PARENT = {
+    "serve.step": None,
+    "serve.admit": "serve.step",
+    "serve.prefill": "serve.step",
+    "serve.prefill.chunk": "serve.prefill",
+    "serve.prefill.wait": "serve.prefill",
+    "serve.scatter": "serve.step",
+    "serve.decode.prepare": "serve.step",
+    "serve.decode.dispatch": "serve.step",
+    "serve.decode.wait": "serve.step",
+    "serve.decode.unroll": "serve.step",
+}
+STEP_ORDER = ["serve.admit", "serve.prefill", "serve.scatter",
+              "serve.decode.prepare", "serve.decode.dispatch",
+              "serve.decode.wait", "serve.decode.unroll"]
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    """Swap the tracer's TraceAnnotation for a recording stand-in; yields
+    the list of spans entered, each knowing its thread's enclosing span."""
+    log, local = [], threading.local()
+
+    class Recorder:
+        def __init__(self, name, **tags):
+            self.name, self.tags, self.children = name, dict(tags), []
+
+        def set_metadata(self, **tags):
+            self.tags.update(tags)
+
+        def __enter__(self):
+            stack = local.__dict__.setdefault("stack", [])
+            self.parent = stack[-1] if stack else None
+            if self.parent is not None:
+                self.parent.children.append(self)
+            stack.append(self)
+            log.append(self)
+            return self
+
+        def __exit__(self, *exc):
+            assert local.stack.pop() is self
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Recorder)
+    return log
+
+
+def test_traced_drain_opens_step_spans_in_order_and_nested(spans):
+    cfg, params = _build("tinyllama-1.1b")
+    server = _server(cfg, params, decode_steps=4)
+    server.tracer.start()
+    for r in _reqs():
+        server.submit(r)
+    server.run_until_drained()
+    assert {sp.name for sp in spans} == set(SPAN_PARENT)
+    for sp in spans:
+        parent = sp.parent.name if sp.parent is not None else None
+        assert parent == SPAN_PARENT[sp.name], (sp.name, parent)
+    steps = [sp for sp in spans if sp.name == "serve.step"]
+    assert [sp.tags["step"] for sp in steps] == list(range(len(steps)))
+    for sp in steps:
+        names = [c.name for c in sp.children]
+        assert names == sorted(names, key=STEP_ORDER.index), names
+        assert len(names) == len(set(names)), names
+        # a step that decoded is tagged with its horizon
+        assert ("k" in sp.tags) == ("serve.decode.dispatch" in names)
+    assert {sp.tags["k"] for sp in steps if "k" in sp.tags} <= {1, 2, 4}
+    for sp in spans:
+        if sp.name == "serve.prefill":
+            kids = [c.name for c in sp.children]
+            assert kids[-1] == "serve.prefill.wait", kids
+            assert set(kids[:-1]) == {"serve.prefill.chunk"}, kids
+        if sp.name == "serve.prefill.chunk":
+            assert 1 <= sp.tags["lanes"] <= server.prefill.lanes
+            assert 1 <= sp.tags["tokens"] <= (sp.tags["lanes"]
+                                               * server.prefill.chunk)
+
+
+def test_traced_frontend_spans_wrap_commands_and_delivery(spans):
+    cfg, params = _build("tinyllama-1.1b")
+    server = _server(cfg, params)
+
+    async def run():
+        async with AsyncEngine(server) as engine:
+            await engine.set_tracing(True)
+            streams = [await engine.submit(r) for r in _reqs()]
+            return [await s.result() for s in streams]
+
+    assert all(r.status == "ok" for r in asyncio.run(run()))
+    front = [sp for sp in spans if sp.name.startswith("serve.frontend.")]
+    assert {sp.name for sp in front} == {"serve.frontend.commands",
+                                         "serve.frontend.deliver"}
+    # frontend spans run on the event loop's thread, outside any step
+    assert all(sp.parent is None for sp in front)
+    n_steps = sum(sp.name == "serve.step" for sp in spans)
+    n_deliver = sum(sp.name == "serve.frontend.deliver" for sp in front)
+    assert n_steps > 0 and n_deliver == n_steps
+
+
+@pytest.mark.parametrize("arch,decode_steps", [("tinyllama-1.1b", 1),
+                                               ("tinyllama-1.1b", 8),
+                                               ("xlstm-1.3b", 8)])
+def test_tracing_adds_no_host_synchronisation(monkeypatch, arch,
+                                              decode_steps):
+    """A traced drain waits on the device exactly where an untraced one
+    does (the engine's device_get, one settle per prefill advance):
+    tracing must not serialise work an untraced step overlaps."""
+    cfg, params = _build(arch)
+    server = _server(cfg, params, decode_steps=decode_steps)
+    counts = {"block_until_ready": 0, "device_get": 0}
+    for name in counts:
+        real = getattr(jax, name)
+
+        def counted(*a, _name=name, _real=real, **k):
+            counts[_name] += 1
+            return _real(*a, **k)
+
+        monkeypatch.setattr(jax, name, counted)
+
+    def drain():
+        for k in counts:
+            counts[k] = 0
+        for r in _reqs():
+            server.submit(r)
+        out = sorted(r.tokens for r in server.run_until_drained())
+        return dict(counts), out
+
+    off, want = drain()
+    server.tracer.start()
+    on, got = drain()
+    assert got == want
+    assert off["device_get"] > 0 and off["block_until_ready"] > 0
+    assert on == off
+    assert server.tracer.summary()["prefill_chunks"] > 0
+
+
+def _stamps_in_order(tracer, rids):
+    order = ("enqueue", "submit", "admit", "prefill_done", "first_token",
+             "finish")
+    stamps: dict = {}
+    for ev in tracer.events():
+        if isinstance(ev, RequestEvent):
+            stamps.setdefault(ev.rid, {})[ev.stage] = ev.t
+    assert set(stamps) == set(rids)
+    for rid in rids:
+        assert set(stamps[rid]) == set(order), stamps[rid]
+        ts = [stamps[rid][s] for s in order]
+        assert ts == sorted(ts), (rid, stamps[rid])
+
+
+def test_request_stamps_ordered_sync():
+    cfg, params = _build("tinyllama-1.1b")
+    server = _server(cfg, params, decode_steps=4)
+    server.tracer.start()
+    ids = [server.submit(r) for r in _reqs()]
+    results = server.run_until_drained()
+    assert all(r.status == "ok" for r in results)
+    _stamps_in_order(server.tracer, ids)
+
+
+def test_request_stamps_ordered_through_frontend():
+    """Through AsyncEngine the enqueue stamp is the client's epoch,
+    taken before the driver applies the submit command."""
+    cfg, params = _build("tinyllama-1.1b")
+    server = _server(cfg, params, decode_steps=4)
+
+    async def run():
+        async with AsyncEngine(server) as engine:
+            await engine.set_tracing(True)
+            streams = await asyncio.gather(
+                *(engine.submit(r) for r in _reqs()))
+            return [await s.result() for s in streams]
+
+    results = asyncio.run(run())
+    assert all(r.status == "ok" for r in results)
+    _stamps_in_order(server.tracer, [r.request_id for r in results])
 
 
 # ---------------------------------------------------------------------------
@@ -954,41 +1187,3 @@ def test_http_slo_routes_and_health_integration():
             await http.wait_closed()
 
     asyncio.run(run())
-
-
-# ---------------------------------------------------------------------------
-# kernel profiling
-# ---------------------------------------------------------------------------
-
-
-def test_profile_serving_kernels_smoke():
-    cfg = registry.get_smoke_config("tinyllama-1.1b").with_(num_instances=2)
-    # the interpreter's timings against v5e peaks: this checks the
-    # record's arithmetic, not a device figure (records say interpret)
-    rows = profile_serving_kernels(cfg, slots=2, max_context=32, chunk=8,
-                                   prefill_lanes=2, repeats=1,
-                                   device_kind="TPU v5 lite")
-    validate_profile(rows)
-    assert [r["kernel"] for r in rows] == [
-        "fused_matmul", "decode_attn", "chunk_prefill_attn",
-        "mlstm_chunk", "slstm_cell", "decode_layer", "logits_sample"]
-    for r in rows:
-        assert r["bound"] in ("compute", "memory")
-        assert r["backend"] == jax.default_backend()
-        if r["backend"] != "tpu":
-            assert r["interpret"] is True
-
-
-def test_serving_shapes_handle_zero_dff_configs():
-    """xlstm smoke configs carry d_ff=0 (no MLP): shape derivation must
-    fall back, not divide by zero (the bug the first profiling run
-    hit)."""
-    cfg = registry.get_smoke_config("xlstm-1.3b").with_(num_instances=2)
-    shapes = serving_shapes(cfg, slots=2, max_context=32, chunk=8,
-                            prefill_lanes=2)
-    assert shapes["fused_matmul"]["f"] > 0
-    assert shapes["mlstm_chunk"]["hd"] > 0
-    assert shapes["slstm_cell"]["d"] > 0
-    row = profile_kernel("fused_matmul", dtype=cfg.dtype, repeats=1,
-                         device_kind="TPU v5 lite", **shapes["fused_matmul"])
-    validate_profile([row])
