@@ -6,7 +6,7 @@ Entry points used by the launcher, tests and benchmarks:
   init / abstract_params / axes
   train_logits(cfg, params, batch)   -> (logits, aux) aligned with labels
   prefill(cfg, params, batch)        -> (last logits, cache)
-  decode_step(cfg, params, cache, tokens, pos) -> (logits, cache)
+  decode_step(cfg, params, cache, tokens, pos, live=None) -> (logits, cache)
   make_cache / abstract_cache / cache_axes
   input_specs(cfg, shape)            -> batch of ShapeDtypeStructs
 
@@ -121,21 +121,30 @@ def prefill(cfg: ModelConfig, params, batch, *, cache_len: int | None = None):
     raise ValueError(fam)
 
 
-def decode_step(cfg: ModelConfig, params, cache, tokens, pos):
-    return family_module(cfg).decode_step(cfg, params, cache, tokens, pos)
+def decode_step(cfg: ModelConfig, params, cache, tokens, pos, *, live=None):
+    """One decode step of the whole grid.  ``live`` (M,B) bool, optional:
+    a lane where it is False leaves its cache/state exactly as it was —
+    the multi-step decode block's freeze of stopped lanes (DESIGN.md
+    §6.6), done at the row or state write."""
+    return family_module(cfg).decode_step(cfg, params, cache, tokens, pos,
+                                          live=live)
 
 
-def decode_step_sample(cfg: ModelConfig, params, cache, tokens, pos):
+def decode_step_sample(cfg: ModelConfig, params, cache, tokens, pos, *,
+                       live=None):
     """Greedy decode step: (next_token (M,B) int32, new cache).
 
     Families with a fused decode+sample path (dense/vlm megakernel:
     final-norm + logits + argmax in one Pallas call) provide their own;
     everything else is argmax over decode_step logits — token-identical
-    to the engine's temperature<=0 sampler either way."""
+    to the engine's temperature<=0 sampler either way.  ``live`` as in
+    :func:`decode_step`."""
     mod = family_module(cfg)
     if hasattr(mod, "decode_step_sample"):
-        return mod.decode_step_sample(cfg, params, cache, tokens, pos)
-    logits, new_cache = mod.decode_step(cfg, params, cache, tokens, pos)
+        return mod.decode_step_sample(cfg, params, cache, tokens, pos,
+                                      live=live)
+    logits, new_cache = mod.decode_step(cfg, params, cache, tokens, pos,
+                                        live=live)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
 
 

@@ -124,7 +124,7 @@ def _sinusoid(n: int, d: int) -> np.ndarray:
 
 
 def _mha(cfg, lp, x, kv_x, *, prefix="", causal, positions=None, q_pos=None,
-         cache=None, decode_pos=None):
+         cache=None, decode_pos=None, live=None):
     """Whisper MHA (no RoPE, learned/sinusoidal positions added outside)."""
     m, b, s, d = x.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -136,7 +136,8 @@ def _mha(cfg, lp, x, kv_x, *, prefix="", causal, positions=None, q_pos=None,
     else:
         k = v = None
     if cache is not None:
-        ck, cv = L.cache_update_one(cache[0], cache[1], k, v, decode_pos)
+        ck, cv = L.cache_update_one(cache[0], cache[1], k, v, decode_pos,
+                                    live=live)
         kv_pos = L.cache_slot_positions(decode_pos, ck.shape[2])
         o = L.flash_attention(q, ck, cv, decode_pos[..., None], kv_pos, causal=True)
         new_cache = (ck, cv)
@@ -240,8 +241,9 @@ def prefill(cfg, params, tokens, frame_embeds, *, cache_len: int | None = None):
     return logits, {"self": KVCache(k=ck, v=cv), "cross_k": xk, "cross_v": xv}
 
 
-def decode_step(cfg, params, cache, tokens, pos):
-    """One decoder token; cross-attention reads precomputed encoder KV."""
+def decode_step(cfg, params, cache, tokens, pos, *, live=None):
+    """One decoder token; cross-attention reads precomputed encoder KV.
+    A lane whose ``live`` is False keeps its self-attention cache."""
     m, b, _ = tokens.shape
     x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
     # learned position embedding at each request's position (pos may vary
@@ -256,7 +258,8 @@ def decode_step(cfg, params, cache, tokens, pos):
     def body(xc, xs):
         lp, ck, cv, xk, xv = xs
         n = L.layer_norm(xc, lp["ln1_s"], lp["ln1_b"], cfg.norm_eps)
-        a, new_cache, _ = _mha(cfg, lp, n, n, causal=True, cache=(ck, cv), decode_pos=pos)
+        a, new_cache, _ = _mha(cfg, lp, n, n, causal=True, cache=(ck, cv),
+                               decode_pos=pos, live=live)
         xc = xc + a
         n = L.layer_norm(xc, lp["ln_x_s"], lp["ln_x_b"], cfg.norm_eps)
         # cross attention against cached encoder K/V

@@ -238,10 +238,11 @@ def tree_select_slots(mask, new_tree, old_tree, axes_tree):
     """Per-(instance, slot) merge of two grid cache trees: slot (m, b)
     takes ``new_tree`` where ``mask[m, b]``, else keeps ``old_tree``.
     The (M, B) mask lands on each leaf's adjacent ``instances``/``batch``
-    dims and broadcasts over the rest.  Used by the multi-step decode
-    scan (DESIGN.md §6.6): a lane that hits its stop condition mid-block
-    freezes — its cache rows stop advancing while live slots keep
-    decoding — so K=1 and K>1 greedy streams are bit-identical."""
+    dims and broadcasts over the rest.  Used by the ssm decode step to
+    freeze a stopped lane's recurrent state (DESIGN.md §6.6): each step
+    rewrites that state whole, so the select costs about what the step
+    does; K/V caches freeze at the row write instead
+    (``layers.cache_update_one``)."""
     mask = jnp.asarray(mask)
 
     def _sel(ax, n, o):

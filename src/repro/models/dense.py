@@ -93,14 +93,17 @@ def axes(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _attn_mlp(cfg: ModelConfig, lp, x, positions, *, window, cache=None, decode_pos=None):
-    """One transformer block; returns (x, new_cache_layer)."""
+def _attn_mlp(cfg: ModelConfig, lp, x, positions, *, window, cache=None,
+              decode_pos=None, **cache_kw):
+    """One transformer block; returns (x, new_cache).  ``cache_kw``
+    (``live``, ``cache_layer``) goes to :func:`layers.gqa_attention`."""
     n = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     h, new_cache = L.gqa_attention(
         n, lp,
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
         positions=positions, window=window, cache=cache, decode_pos=decode_pos,
+        **cache_kw,
     )
     x = x + h
     n = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -264,11 +267,14 @@ def _prefill_chunk_embeds(cfg: ModelConfig, params, x, carry, offset, valid=None
     return {"cache": KVCache(k=nk, v=nv)}
 
 
-def _decode_layers_fused(cfg: ModelConfig, params, cache: KVCache, x, pos):
+def _decode_layers_fused(cfg: ModelConfig, params, cache: KVCache, x, pos,
+                         live=None):
     """Megakernel decode body: one Pallas launch per layer
     (kernels/decode_layer.py) — norms, QKV+RoPE, in-kernel ring append,
     flash decode attention, out-proj, SwiGLU all fused over the (M, B)
-    grid.  x: (M,B,D) residual; returns (x_out, updated cache)."""
+    grid.  x: (M,B,D) residual; returns (x_out, updated cache).  The
+    in-kernel append takes no lane mask, so a lane whose ``live`` is
+    False gets its old layer back by a select over the layer's cache."""
     from repro.kernels import ops as K
     from repro.models.common import active_rules
 
@@ -281,49 +287,55 @@ def _decode_layers_fused(cfg: ModelConfig, params, cache: KVCache, x, pos):
             head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
             window=cfg.sliding_window, eps=cfg.norm_eps, rules=rules,
         )
+        if live is not None:
+            keep = live[:, :, None, None, None]
+            nk, nv = jnp.where(keep, nk, ck), jnp.where(keep, nv, cv)
         return out, (nk, nv)
 
     x, (nk, nv) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
     return x, KVCache(k=nk, v=nv)
 
 
-def decode_step(cfg: ModelConfig, params, cache: KVCache, tokens, pos):
+def decode_step(cfg: ModelConfig, params, cache: KVCache, tokens, pos, *,
+                live=None):
     """One decode step. tokens (M,B,1); pos (M,B) = index of this token.
+    ``live`` (M,B) bool, optional: lanes where it is False leave the
+    cache exactly as it was (their logits are computed and ignored).
     Returns (logits (M,B,V), updated cache)."""
     x = _embed_in(cfg, params, tokens)
     if cfg.use_pallas_kernels:
-        x, new_cache = _decode_layers_fused(cfg, params, cache, x[:, :, 0], pos)
+        x, new_cache = _decode_layers_fused(cfg, params, cache, x[:, :, 0],
+                                            pos, live)
         logits = _logits(cfg, params, x[:, :, None])[:, :, 0]
         return logits, new_cache
     positions = pos[..., None]
     window = cfg.sliding_window
-    # The layer loop CARRIES the stacked cache and writes each layer back
-    # in place, rather than scanning it in and out (a second full cache).
-    # It is carried with (KVH, hd) merged into one lane-dense dim: on a
-    # TPU a head_dim-64 minor dim pads to 128 lanes, so the loop would
-    # otherwise hold padded copies of the cache at twice its size.
-    layer_shape = cache.k.shape[1:]
-    flat = lambda a: a.reshape(a.shape[:-2] + (-1,))
+    # The layer loop CARRIES the stacked cache and writes each token's
+    # K/V row into it in place: no layer is copied out and back, and
+    # nothing is scanned in and out (a second full cache).  It is carried
+    # in the kv_stack layout, the cache's own physical order on a TPU,
+    # so the carry, the row writes and attention's reads need no
+    # relayout.
+    kvh, hd = cfg.num_kv_heads, cfg.head_dim
 
     def body(carry, lp):
         xc, ks, vs, i = carry
-        out, (nk, nv) = _attn_mlp(
-            cfg, lp, xc, positions, window=window,
-            cache=(ks[i].reshape(layer_shape), vs[i].reshape(layer_shape)),
-            decode_pos=pos,
+        out, (ks, vs) = _attn_mlp(
+            cfg, lp, xc, positions, window=window, cache=(ks, vs),
+            decode_pos=pos, live=live, cache_layer=i,
         )
-        ks = lax.dynamic_update_index_in_dim(ks, flat(nk), i, 0)
-        vs = lax.dynamic_update_index_in_dim(vs, flat(nv), i, 0)
         return (out, ks, vs, i + 1), None
 
     (x, nk, nv, _), _ = lax.scan(
-        body, (x, flat(cache.k), flat(cache.v), 0), params["layers"])
+        body, (x, L.kv_stack(cache.k), L.kv_stack(cache.v), 0),
+        params["layers"])
     logits = _logits(cfg, params, x)[:, :, 0]
-    return logits, KVCache(k=nk.reshape(cache.k.shape),
-                           v=nv.reshape(cache.v.shape))
+    return logits, KVCache(k=L.kv_unstack(nk, kvh, hd),
+                           v=L.kv_unstack(nv, kvh, hd))
 
 
-def decode_step_sample(cfg: ModelConfig, params, cache: KVCache, tokens, pos):
+def decode_step_sample(cfg: ModelConfig, params, cache: KVCache, tokens, pos,
+                       *, live=None):
     """Greedy decode step: returns (next_token (M,B) int32, new cache).
 
     With ``cfg.use_pallas_kernels`` the final-norm + logits projection +
@@ -336,7 +348,7 @@ def decode_step_sample(cfg: ModelConfig, params, cache: KVCache, tokens, pos):
         from repro.models.common import active_rules
 
         x = _embed_in(cfg, params, tokens)[:, :, 0]
-        x, new_cache = _decode_layers_fused(cfg, params, cache, x, pos)
+        x, new_cache = _decode_layers_fused(cfg, params, cache, x, pos, live)
         head = (
             jnp.swapaxes(params["embed"], -1, -2) if cfg.tie_embeddings
             else params["lm_head"]
@@ -344,7 +356,7 @@ def decode_step_sample(cfg: ModelConfig, params, cache: KVCache, tokens, pos):
         tok = K.logits_sample(x, params["final_norm"], head,
                               eps=cfg.norm_eps, rules=active_rules())
         return tok, new_cache
-    logits, new_cache = decode_step(cfg, params, cache, tokens, pos)
+    logits, new_cache = decode_step(cfg, params, cache, tokens, pos, live=live)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
 
 

@@ -310,7 +310,7 @@ def _norm_branch(y, scale, eps):
 def hymba_block(
     cfg, lp, x, positions, window, *,
     kv_cache=None, decode_pos=None, cache_slot=None, cache_kv_pos=None,
-    ssm_state=None, kernel_attn: bool = False,
+    ssm_state=None, kernel_attn: bool = False, live=None,
 ):
     """One hybrid block. window: static int or traced scalar.
     ``kernel_attn`` routes decode attention through the Pallas
@@ -324,9 +324,15 @@ def hymba_block(
         positions=positions, window=window, sink=NUM_META_TOKENS,
         cache=kv_cache, decode_pos=decode_pos,
         cache_slot=cache_slot, cache_kv_pos=cache_kv_pos,
-        use_kernel=kernel_attn,
+        use_kernel=kernel_attn, live=live,
     )
     ssm_out, new_ssm = mamba_branch(cfg, lp, xn, state=ssm_state)
+    if live is not None:
+        # a stopped lane keeps its mamba state (DESIGN.md §6.6)
+        new_ssm = jax.tree.map(
+            lambda n, o: jnp.where(
+                live.reshape(live.shape + (1,) * (n.ndim - 2)), n, o),
+            new_ssm, ssm_state)
     fused = 0.5 * (
         _norm_branch(attn_out, lp["attn_out_norm"], cfg.norm_eps)
         + _norm_branch(ssm_out, lp["ssm_out_norm"], cfg.norm_eps)
@@ -406,9 +412,10 @@ def _swa_slot_positions(pos, s_cache):
     return jnp.concatenate([meta, ring], axis=-1)
 
 
-def decode_step(cfg, params, cache, tokens, pos):
+def decode_step(cfg, params, cache, tokens, pos, *, live=None):
     """tokens (M,B,1); pos (M,B) absolute position INCLUDING the meta
-    offset (first real token decodes at pos = NUM_META_TOKENS + prompt)."""
+    offset (first real token decodes at pos = NUM_META_TOKENS + prompt).
+    A lane whose ``live`` is False keeps its K/V rows and mamba states."""
     m, b, _ = tokens.shape
     x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
     positions = pos[..., None]
@@ -442,6 +449,7 @@ def decode_step(cfg, params, cache, tokens, pos):
                 kv_cache=(ck, cv), decode_pos=pos,
                 cache_slot=slot, cache_kv_pos=kv_pos,
                 ssm_state={"h": sh, "conv": sconv}, kernel_attn=kattn,
+                live=live,
             )
             return out, (nkv[0], nkv[1], nssm["h"], nssm["conv"])
 

@@ -21,6 +21,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.core import fused_ops
 from repro.models.common import active_rules, constrain
@@ -380,97 +381,150 @@ def cache_append_chunk(
     return out.reshape(m, b, s, kvh, hd)
 
 
+LANES = 128  # the minor dim of a TPU tile
+
+
+def kv_stack(c: jax.Array) -> jax.Array:
+    """A (..., S, KVH, hd) K/V cache in its physical order on a TPU.
+    Where hd is not a multiple of the 128 lanes the TPU lays the cache
+    out with S minor rather than pad hd, and the view is (..., KVH*hd,
+    S); else the cache is row-major and returned as it is.  XLA makes
+    the view a bitcast, and in-place writes of one token's slot into it
+    need no relayout of the cache."""
+    if c.shape[-1] % LANES == 0:
+        return c
+    return jnp.swapaxes(c.reshape(c.shape[:-2] + (-1,)), -1, -2)
+
+
+def kv_unstack(c: jax.Array, kv_heads: int, head_dim: int) -> jax.Array:
+    """Inverse of :func:`kv_stack`: back to (..., S, KVH, hd)."""
+    if head_dim % LANES == 0:
+        return c
+    c = jnp.swapaxes(c, -1, -2)
+    return c.reshape(c.shape[:-1] + (kv_heads, head_dim))
+
+
 def cache_update_one(
-    cache_k_layer: jax.Array,
-    cache_v_layer: jax.Array,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
     k_new: jax.Array,
     v_new: jax.Array,
     pos: jax.Array,
     slot: jax.Array | None = None,
+    live: jax.Array | None = None,
+    layer: jax.Array | int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Insert one token's k/v at slot pos % S (or an explicit slot) for
     every (m, b).
 
-    cache_*_layer: (M,B,S,KVH,hd); k_new/v_new: (M,B,1,KVH,hd); pos: (M,B).
+    cache_*: one layer's (M,B,S,KVH,hd); or, with ``layer``, every
+    layer's stack, (L,M,B,S,KVH,hd) or the slots-minor :func:`kv_stack`
+    view (L,M,B,KVH*hd,S) that the dense decode scan carries, written at
+    that layer.  k_new/v_new: (M,B,1,KVH,hd); pos: (M,B).  ``live``
+    (M,B) bool: a lane where it is False writes back what it had, so a
+    stopped lane's cache stays bit for bit as it was.  Each lane reads
+    and writes one slot of one layer (a window of up to 128 slots where
+    S is minor); the rest of the cache is never touched.
     """
-    m, b, s, kvh, hd = cache_k_layer.shape
+    if layer is None:
+        ck, cv = cache_update_one(cache_k[None], cache_v[None], k_new,
+                                  v_new, pos, slot, live, layer=0)
+        return ck[0], cv[0]
     if slot is None:
-        slot = pos % s
+        slot = pos % cache_k.shape[-1 if cache_k.ndim == 5 else 3]
     slot = slot.astype(jnp.int32)
+    if live is None:
+        live = jnp.ones(slot.shape, bool)
+    layer = jnp.asarray(layer, jnp.int32)
+    rows = (k_new[:, :, 0], v_new[:, :, 0])
 
     rules = active_rules()
     if rules is not None:
         return _cache_update_sharded(
-            rules, cache_k_layer, cache_v_layer, k_new, v_new, slot
-        )
-
-    def upd(c, x, i):
-        return lax.dynamic_update_slice(c, x, (i, 0, 0))
-
-    ck = jax.vmap(upd)(
-        cache_k_layer.reshape(m * b, s, kvh, hd),
-        k_new.astype(cache_k_layer.dtype).reshape(m * b, 1, kvh, hd),
-        slot.reshape(m * b),
-    ).reshape(m, b, s, kvh, hd)
-    cv = jax.vmap(upd)(
-        cache_v_layer.reshape(m * b, s, kvh, hd),
-        v_new.astype(cache_v_layer.dtype).reshape(m * b, 1, kvh, hd),
-        slot.reshape(m * b),
-    ).reshape(m, b, s, kvh, hd)
-    return ck, cv
+            rules, cache_k, cache_v, *rows, slot, live, layer)
+    return _write_slots((cache_k, cache_v), rows, layer, slot, live)
 
 
-def _cache_update_sharded(rules, ck, cv, k_new, v_new, slot):
+def _write_slots(caches, rows, layer, slot, live):
+    """Write ``rows[i][m, b]`` (KVH, hd) at slot ``slot[m, b]`` of layer
+    ``layer`` of ``caches[i]`` (as :func:`cache_update_one` takes them)
+    where ``live[m, b]``.  One lane at a time, through a window of the
+    slots that share the written one's tile: 128 slots where S is minor,
+    else the one slot.  The whole window is written, its other slots
+    with what they held."""
+    m, b = slot.shape
+    shape = caches[0].shape
+    minor = len(shape) == 5                 # (L,M,B,KVH*hd,S)
+    s = shape[-1] if minor else shape[3]
+    w = min(s, LANES) if minor else 1
+    size = (1, 1, 1) + ((shape[3], w) if minor else (1,) + shape[4:])
+    slots = jnp.arange(w, dtype=jnp.int32)
+    zero = jnp.int32(0)
+
+    def one(j, caches):
+        mi, bi = j // b, j % b
+        sl = slot[mi, bi]
+        start = jnp.minimum(sl // w * w, s - w)
+        at = (layer, mi, bi) + ((zero, start) if minor else (start, zero, zero))
+        hit = (slots == sl - start) & live[mi, bi]
+        out = []
+        for c, r in zip(caches, rows):
+            r = r[mi, bi].astype(c.dtype)
+            r, h = ((r.reshape(-1, 1), hit[None, :]) if minor
+                    else (r[None], hit[:, None, None]))
+            win = lax.dynamic_slice(c, at, size)
+            out.append(lax.dynamic_update_slice(c, jnp.where(h, r, win), at))
+        return tuple(out)
+
+    return lax.fori_loop(0, m * b, one, tuple(caches))
+
+
+def _cache_update_sharded(rules, ck, cv, k_new, v_new, slot, live, layer):
     """Ring-buffer insert when the cache's context dim is sharded
     (cache_seq -> "model", §Perf tinyllama-decode iteration).
 
-    A dynamic-update-slice along a sharded dim with a data-dependent slot
-    would make GSPMD replicate the whole cache; instead each device checks
-    whether the slot falls inside its local context shard and does a local
-    DUS (no collectives beyond broadcasting the 1-token k/v)."""
-    m, b, s, kvh, hd = ck.shape
-    cache_logical = ("instances", "batch", "cache_seq", "kv_heads", "kv_hd")
-    cache_spec = rules.spec(cache_logical, ck.shape)
-    new_spec = rules.spec(("instances", "batch", None, None, None), k_new.shape)
-    slot_spec = rules.spec(("instances", "batch"), slot.shape)
-    seq_axes = cache_spec[2]  # mesh axes carrying the context dim (or None)
+    A write along a sharded dim at a data-dependent slot would make
+    GSPMD replicate the whole cache; instead each device checks whether
+    the slot falls inside its local context shard and writes its rows
+    there (no collectives beyond broadcasting the 1-token k/v).  The
+    merged KVH*hd dim of a slots-minor stack takes the kv heads' mesh
+    axes unless the head dim is split too."""
+    minor = ck.ndim == 5
+    kvh, hd = k_new.shape[-2:]
+    s_axis = 4 if minor else 3
+    spec = rules.spec(
+        ("layers", "instances", "batch", "cache_seq", "kv_heads", "kv_hd"),
+        ck.shape[:3] + (ck.shape[s_axis], kvh, hd))
+    if minor:
+        f_ax = spec[4] if spec[5] is None else None
+        cache_spec = P(*spec[:3], f_ax, spec[3])
+        new_spec = P(spec[1], spec[2], f_ax, None)
+    else:
+        cache_spec = spec
+        new_spec = P(spec[1], spec[2], spec[4], spec[5])
+    grid_spec = P(spec[1], spec[2])
+    seq_axes = spec[3]  # mesh axes carrying the context dim (or None)
     seq_axes = (
         (seq_axes,) if isinstance(seq_axes, str) else tuple(seq_axes or ())
     )
 
-    def body(ck_l, cv_l, kn_l, vn_l, slot_l):
-        s_l = ck_l.shape[2]
+    def body(ck_l, cv_l, kn_l, vn_l, slot_l, live_l):
+        s_l = ck_l.shape[s_axis]
         shard = jnp.int32(0)
         for a in seq_axes:
             shard = shard * rules.mesh.shape[a] + lax.axis_index(a)
-        start = shard * s_l
-        loc = slot_l - start                       # (m_l, b_l) local slot
-        ok = (loc >= 0) & (loc < s_l)
-        idx = jnp.clip(loc, 0, s_l - 1).reshape(-1)
-        ok = ok.reshape(-1)
-        m_l, b_l = ck_l.shape[0], ck_l.shape[1]
-
-        def upd(c, x, i, o):
-            cur = lax.dynamic_slice(c, (i, 0, 0), (1,) + c.shape[1:])
-            neww = jnp.where(o, x, cur)
-            return lax.dynamic_update_slice(c, neww, (i, 0, 0))
-
-        outs = []
-        for c_l, x_l in ((ck_l, kn_l), (cv_l, vn_l)):
-            r = jax.vmap(upd)(
-                c_l.reshape(m_l * b_l, s_l, *c_l.shape[3:]),
-                x_l.astype(c_l.dtype).reshape(m_l * b_l, 1, *c_l.shape[3:]),
-                idx, ok,
-            )
-            outs.append(r.reshape(c_l.shape))
-        return outs[0], outs[1]
+        loc = slot_l - shard * s_l                 # (m_l, b_l) local slot
+        ok = live_l & (loc >= 0) & (loc < s_l)
+        return _write_slots((ck_l, cv_l), (kn_l, vn_l), layer,
+                            jnp.clip(loc, 0, s_l - 1), ok)
 
     return jax.shard_map(
         body, mesh=rules.mesh,
-        in_specs=(cache_spec, cache_spec, new_spec, new_spec, slot_spec),
+        in_specs=(cache_spec, cache_spec, new_spec, new_spec, grid_spec,
+                  grid_spec),
         out_specs=(cache_spec, cache_spec),
         check_vma=False,
-    )(ck, cv, k_new, v_new, slot)
+    )(ck, cv, k_new, v_new, slot, live)
 
 
 # ---------------------------------------------------------------------------
@@ -496,6 +550,8 @@ def gqa_attention(
     cache_kv_pos: jax.Array | None = None,
     kv_override: tuple[jax.Array, jax.Array] | None = None,
     use_kernel: bool = False,
+    live: jax.Array | None = None,
+    cache_layer: jax.Array | None = None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
     """Merged multi-instance GQA attention.
 
@@ -504,7 +560,10 @@ def gqa_attention(
 
     * train/prefill: cache is None — self-attention over x.
     * decode: cache=(k,v) for this layer, decode_pos (M,B) — S must be 1;
-      returns the updated cache.
+      returns the updated cache; a lane whose ``live`` is False keeps
+      its cache as it was (:func:`cache_update_one`).  With
+      ``cache_layer`` the cache is every layer's stack in the
+      :func:`kv_stack` layout, written in place at that layer.
     * cross-attention: kv_override provides precomputed (k, v).
     """
     m, b, s, d = x.shape
@@ -539,8 +598,11 @@ def gqa_attention(
     new_cache = None
     if cache is not None:
         assert s == 1 and decode_pos is not None
-        ck, cv = cache_update_one(cache[0], cache[1], k, v, decode_pos, slot=cache_slot)
-        new_cache = (ck, cv)
+        ck, cv = new_cache = cache_update_one(
+            cache[0], cache[1], k, v, decode_pos, slot=cache_slot,
+            live=live, layer=cache_layer)
+        if cache_layer is not None:
+            ck, cv = (kv_unstack(c[cache_layer], kvh, hd) for c in new_cache)
         s_cache = ck.shape[2]
         kv_pos = (
             cache_kv_pos if cache_kv_pos is not None

@@ -427,20 +427,21 @@ def moe_mlp(cfg: ModelConfig, lp, x, *, valid=None, counts=None, limit=None):
 # ---------------------------------------------------------------------------
 
 
-def _attn(cfg, lp, x, positions, *, cache=None, decode_pos=None):
+def _attn(cfg, lp, x, positions, *, cache=None, decode_pos=None, live=None):
     n = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     h, new_cache = L.gqa_attention(
         n, lp,
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
         positions=positions, window=cfg.sliding_window,
-        cache=cache, decode_pos=decode_pos,
+        cache=cache, decode_pos=decode_pos, live=live,
     )
     return x + h, new_cache
 
 
-def _block(cfg, lp, x, positions, *, cache=None, decode_pos=None):
-    x, new_cache = _attn(cfg, lp, x, positions, cache=cache, decode_pos=decode_pos)
+def _block(cfg, lp, x, positions, *, cache=None, decode_pos=None, live=None):
+    x, new_cache = _attn(cfg, lp, x, positions, cache=cache,
+                         decode_pos=decode_pos, live=live)
     n = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     y, aux = moe_mlp(cfg, lp, n)
     return x + y, new_cache, aux
@@ -503,13 +504,15 @@ def prefill(cfg, params, tokens, *, cache_len: int | None = None):
     return L.unembed(x, params["lm_head"])[:, :, 0], KVCache(k=ck, v=cv)
 
 
-def decode_step(cfg, params, cache: KVCache, tokens, pos):
+def decode_step(cfg, params, cache: KVCache, tokens, pos, *, live=None):
+    """One decode step; a lane whose ``live`` is False keeps its cache."""
     x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
     positions = pos[..., None]
 
     def body(xc, xs):
         lp, ck, cv = xs
-        out, new_cache, _ = _block(cfg, lp, xc, positions, cache=(ck, cv), decode_pos=pos)
+        out, new_cache, _ = _block(cfg, lp, xc, positions, cache=(ck, cv),
+                                   decode_pos=pos, live=live)
         return out, new_cache
 
     x, (nk, nv) = lax.scan(body, x, (params["layers"], cache.k, cache.v))

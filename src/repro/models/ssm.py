@@ -33,7 +33,7 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models.common import (
     Factory, constrain, make_factory, param_axes, param_values,
-    stack_layer_params,
+    stack_layer_params, tree_select_slots,
 )
 
 # ---------------------------------------------------------------------------
@@ -548,12 +548,16 @@ def prefill(cfg, params, tokens, *, state=None):
     return L.unembed(x, params["lm_head"])[:, :, 0], states
 
 
-def decode_step(cfg, params, states, tokens, pos=None):
-    """One token. tokens (M,B,1). pos unused (state is positionless)."""
+def decode_step(cfg, params, states, tokens, pos=None, *, live=None):
+    """One token. tokens (M,B,1). pos unused (state is positionless).
+    A lane whose ``live`` is False keeps its state (a select over the
+    per-slot recurrent state, which every step rewrites whole anyway)."""
     x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
-    x, states = _trunk(cfg, params, x, states=states)
+    x, new = _trunk(cfg, params, x, states=states)
+    if live is not None:
+        new = tree_select_slots(live, new, states, state_axes(cfg))
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return L.unembed(x, params["lm_head"])[:, :, 0], states
+    return L.unembed(x, params["lm_head"])[:, :, 0], new
 
 
 def make_state(cfg, m, b):

@@ -28,10 +28,10 @@ whole grid — ONE dispatch returns a (K, M, B) token block, amortizing
 per-launch overhead K-fold on top of the paper's M-fold merge.  Stop
 conditions live on-device: the scan carries a per-slot alive mask, and
 a lane that hits EOS / ``max_new_tokens`` / ``max_context`` mid-block
-freezes — its token and position stop advancing and its cache writes
-are masked (``tree_select_slots``, mirroring the tail-folding ``valid``
-machinery) — so K=1 and K>1 greedy streams are bit-identical per
-request.  The historical one-call-per-*step* invariant is thus now
+freezes — its token and position stop advancing and the decode step,
+given the mask as ``live``, writes its K/V row or recurrent state back
+unchanged (mirroring the tail-folding ``valid`` machinery) — so K=1
+and K>1 greedy streams are bit-identical per request.  The historical one-call-per-*step* invariant is thus now
 one-call-per-*block*: ``step()`` still makes exactly one fused decode
 dispatch, but unrolls the block on the host so per-token ``on_token``
 callbacks, metrics, scheduler accounting and finish detection keep
@@ -273,10 +273,10 @@ class MultiModelServer:
         one device call, with on-device stop handling.
 
         Carry: (tok, pos, cache, key, alive, remaining).  Each scan step
-        decodes + samples the whole grid, then masks dead lanes: their
-        token/position/budget freeze (``jnp.where``) and — for k > 1 —
-        their cache writes are reverted (``tree_select_slots``), so a
-        lane stopping mid-block leaves cache and position exactly as the
+        decodes + samples the whole grid with ``live=alive``: a dead
+        lane's K/V row or recurrent state is written back as it was, and
+        its token/position/budget freeze (``jnp.where``), so a lane
+        stopping mid-block leaves cache and position exactly as the
         one-call-per-token protocol would.  Stop mirrors the host finish
         logic bit-for-bit: budget exhausted (remaining), EOS, or
         position reaching ``max_context - 1``.  Returns the (k, M, B)
@@ -298,26 +298,21 @@ class MultiModelServer:
         def _block_impl(params, cache, tok, pos, key, alive, remaining):
             def body(carry, _):
                 tok, pos, cache, key, alive, remaining = carry
+                # a stopped lane's cache/state freezes where it is
+                # written: its K/V row or recurrent state is written back
+                # unchanged, so nothing cache-sized is selected or copied
                 if fused_sample:
                     picked, new_cache = api.decode_step_sample(
-                        cfg, params, cache, tok[..., None], pos
+                        cfg, params, cache, tok[..., None], pos, live=alive
                     )
                     ok = jnp.ones_like(alive)
                 else:
                     logits, new_cache = api.decode_step(
-                        cfg, params, cache, tok[..., None], pos
+                        cfg, params, cache, tok[..., None], pos, live=alive
                     )
                     ok = jnp.all(
                         jnp.isfinite(logits), axis=-1
                     ).reshape(alive.shape)
-                if k > 1:
-                    # freeze stopped lanes' state between scan steps (at
-                    # k == 1 every junk write is overwritten by scatter
-                    # before the slot decodes again — the historical
-                    # protocol — so the masking would be dead weight)
-                    new_cache = C.tree_select_slots(
-                        alive, new_cache, cache, cache_ax
-                    )
                 # pin the grid cache to the rules' layout across steps
                 # (no-op without active rules), so donation reuses the
                 # buffers and the layout never drifts from the

@@ -79,6 +79,36 @@ def test_flash_attention_decode_matches_prefill():
         )
 
 
+@pytest.mark.parametrize("s,hd", [(8, 16), (200, 16), (16, 128)])
+def test_cache_update_one_writes_one_slot_of_live_lanes(s, hd):
+    """The K/V insert writes each live lane's row at slot pos % S of its
+    layer and nothing else, whether given one layer or the stacked cache
+    in its kv_stack view (slots-minor for hd=16, including a ring longer
+    than one 128-slot window; as it is for hd=128)."""
+    nl, m, b, kvh, layer = 3, 2, 3, 2, 1
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    ck, cv = (jax.random.normal(k, (nl, m, b, s, kvh, hd)) for k in ks[:2])
+    kn, vn = (jax.random.normal(k, (m, b, 1, kvh, hd)) for k in ks[2:4])
+    pos = jax.random.randint(ks[4], (m, b), 0, 3 * s)
+    live = jnp.array([[True, False, True], [False, True, True]])
+
+    want = [np.array(c) for c in (ck, cv)]
+    for mi in range(m):
+        for bi in range(b):
+            if live[mi, bi]:
+                for w, n in zip(want, (kn, vn)):
+                    w[layer, mi, bi, int(pos[mi, bi]) % s] = n[mi, bi, 0]
+
+    got = jax.jit(lambda a, c: L.cache_update_one(
+        L.kv_stack(a), L.kv_stack(c), kn, vn, pos, live=live,
+        layer=jnp.int32(layer)))(ck, cv)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(L.kv_unstack(g, kvh, hd)), w)
+    got = L.cache_update_one(ck[layer], cv[layer], kn, vn, pos, live=live)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w[layer])
+
+
 def test_ring_buffer_sliding_window_decode():
     """With cache size == window, ring-buffer decode == sliding-window
     attention over the full sequence."""

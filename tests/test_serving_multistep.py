@@ -14,6 +14,7 @@ ceil(max_new / K) decode device calls per request wave.
 import asyncio
 import math
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -164,22 +165,43 @@ def test_decode_device_calls_ceil_tokens_over_k(k, max_new):
 # ---------------------------------------------------------------------------
 
 
-def test_midblock_stop_freezes_cache_and_tokens():
+def _max_context(cfg):
+    # a hybrid grid must hold its meta tokens plus a whole SWA window
+    if cfg.family == "hybrid":
+        from repro.models import hybrid
+        return hybrid.NUM_META_TOKENS + hybrid.swa_window(cfg) + 16
+    return 48
+
+
+@pytest.mark.parametrize("arch,dead_at_entry", [
+    ("tinyllama-1.1b", False),
+    ("tinyllama-1.1b", True),
+    ("hymba-1.5b", True),
+    ("xlstm-1.3b", True),
+])
+def test_midblock_stop_freezes_cache_and_tokens(arch, dead_at_entry):
     """Drive the block function directly: a lane whose budget runs out
     after 2 of 4 scan steps must leave EXACTLY the cache a 2-step block
     leaves (junk steps masked), with its tokens frozen and the emitted
-    mask marking the junk rows; a live lane keeps decoding."""
-    cfg, params = _build("tinyllama-1.1b")
-    mk = lambda: _server(cfg, params, decode_steps=4)
+    mask marking the junk rows; a live lane keeps decoding.  A lane dead
+    at block entry leaves its cache/state as it found it.  Run for a
+    KV-cache family, a hybrid (K/V rows plus mamba states) and a
+    recurrent-state family."""
+    cfg, params = _build(arch)
+    mk = lambda: _server(cfg, params, decode_steps=4,
+                         max_context=_max_context(cfg))
     srv = mk()
     M, B = srv.m, srv.b
     tok = jnp.ones((M, B), jnp.int32)
     pos = jnp.zeros((M, B), jnp.int32)
     key = jax.random.PRNGKey(7)
     alive = jnp.ones((M, B), bool)
+    if dead_at_entry:
+        alive = alive.at[1, 1].set(False)
     # slot (0, 0) has budget for 2 steps; everyone else rides the full 4
     rem = jnp.full((M, B), 10, jnp.int32).at[0, 0].set(2)
 
+    before = jax.tree.map(np.asarray, srv.cache)
     toks4, em4, ok4, cache4, _ = srv._step(
         srv.params, srv.cache, tok, pos, key, alive, rem, 4)
     srv2 = mk()
@@ -197,17 +219,25 @@ def test_midblock_stop_freezes_cache_and_tokens():
     assert (toks4[:2] == toks2).all()
     assert toks4[2, 0, 0] == toks4[1, 0, 0] == toks4[3, 0, 0]
 
+    def same(x, y):
+        for a, b in zip(jax.tree.leaves(x), jax.tree.leaves(y)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     # the stopped lane's cache is bit-identical to the 2-step block's —
     # the junk steps wrote nothing
-    s4 = api.take_state(cfg, cache4, 0, 0)
-    s2 = api.take_state(cfg, cache2, 0, 0)
-    for a, b in zip(jax.tree.leaves(s4), jax.tree.leaves(s2)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    same(api.take_state(cfg, cache4, 0, 0), api.take_state(cfg, cache2, 0, 0))
     # while a live lane's cache DID advance past the 2-step state
     l4 = jax.tree.leaves(api.take_state(cfg, cache4, 1, 0))
     l2 = jax.tree.leaves(api.take_state(cfg, cache2, 1, 0))
     assert any((np.asarray(a) != np.asarray(b)).any()
                for a, b in zip(l4, l2))
+    if dead_at_entry:
+        # the dead lane emitted nothing, kept its token, and left its
+        # cache/state byte for byte as the block found it
+        assert not em4[:, 1, 1].any()
+        assert (toks4[:, 1, 1] == 1).all()
+        same(api.take_state(cfg, cache4, 1, 1),
+             api.take_state(cfg, before, 1, 1))
 
 
 def test_eos_midblock_matches_k1():
@@ -236,6 +266,41 @@ def test_eos_midblock_matches_k1():
     assert stopped.finish_reason == "stop"
     assert stopped.tokens[-1] == eos
     assert len(stopped.tokens) < 8
+
+
+# ---------------------------------------------------------------------------
+# the freeze costs no cache-sized work: the compiled block programs
+# ---------------------------------------------------------------------------
+
+
+def _cache_sized_ops(srv, k):
+    """Instruction counts, by opcode, of the compiled k-step decode block
+    (cache donated) whose result has as many elements as a cache leaf."""
+    m, b = srv.m, srv.b
+    z = jnp.zeros((m, b), jnp.int32)
+    text = srv._make_block(k).lower(
+        srv.params, srv.cache, z, z, jax.random.PRNGKey(0),
+        jnp.ones((m, b), bool), z).compile().as_text()
+    sizes = {leaf.size for leaf in jax.tree.leaves(srv.cache)}
+    counts: dict[str, int] = {}
+    for shape, op in re.findall(
+            r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", text):
+        if math.prod(int(d) for d in shape.split(",") if d) in sizes:
+            counts[op] = counts.get(op, 0) + 1
+    return counts
+
+
+@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "hymba-1.5b"])
+def test_block_freeze_adds_no_cache_sized_select_or_copy(arch):
+    """A stopped lane freezes at its K/V row or state write, not by a
+    select over the whole cache: the k=4 block has no cache-sized
+    select, and no more cache-sized copies than the k=1 block."""
+    cfg, params = _build(arch)
+    srv = _server(cfg, params, decode_steps=4, donate=True,
+                  max_context=_max_context(cfg))
+    one, four = _cache_sized_ops(srv, 1), _cache_sized_ops(srv, 4)
+    assert four.get("select", 0) == 0, four
+    assert four.get("copy", 0) <= one.get("copy", 0), (one, four)
 
 
 # ---------------------------------------------------------------------------
